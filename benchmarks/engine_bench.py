@@ -30,15 +30,16 @@ as the client count grows (the loop engine is O(clients) per round, the
 sharded engine O(pack) given enough devices).  Emits a machine-readable
 JSON artifact so CI records the trajectory:
 
-  PYTHONPATH=src python benchmarks/engine_bench.py                 # full sweep
-  PYTHONPATH=src python benchmarks/engine_bench.py --quick \\
-      --out BENCH_engines.json                                     # CI smoke
-  PYTHONPATH=src python benchmarks/engine_bench.py --hotpath \\
-      --out BENCH_hotpath.json      # §13 hot-path gate vs the PR 6 baseline
-  PYTHONPATH=src python benchmarks/engine_bench.py --waves \\
-      --out BENCH_waves.json        # §15 wave-scaling gate: same cohort on
-                                    # the same mesh at a 100x larger client
-                                    # universe must hold steady round time
+  # the CPU is a stated choice; on a TPU host drop JAX_PLATFORMS=cpu
+  JAX_PLATFORMS=cpu PYTHONPATH=src python benchmarks/engine_bench.py  # sweep
+  JAX_PLATFORMS=cpu PYTHONPATH=src python benchmarks/engine_bench.py \\
+      --quick --out BENCH_engines.json                             # CI smoke
+  JAX_PLATFORMS=cpu PYTHONPATH=src python benchmarks/engine_bench.py \\
+      --hotpath --out BENCH_hotpath.json  # §13 hot-path gate
+  JAX_PLATFORMS=cpu PYTHONPATH=src python benchmarks/engine_bench.py \\
+      --waves --out BENCH_waves.json  # §15 wave-scaling gate: same cohort
+                                      # on the same mesh at a 100x larger
+                                      # universe must hold steady round time
 """
 import argparse
 import json
@@ -46,9 +47,10 @@ import os
 import platform
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import time
+
+import jax
 
 from repro import perf
 from repro.data.synthetic import load_dataset
@@ -208,6 +210,7 @@ def main():
                          "BENCH_waves.json under --waves, "
                          "BENCH_engines.json otherwise)")
     args = ap.parse_args()
+    print(f"jax backend: {jax.default_backend()} ({len(jax.devices())} devices)")
     if args.out is None:
         args.out = ("BENCH_hotpath.json" if args.hotpath else
                     "BENCH_waves.json" if args.waves else

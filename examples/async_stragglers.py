@@ -19,19 +19,21 @@ The sweep varies the staleness bound ``max_staleness`` in {0, 2, 4}:
 Teachers stay synchronous throughout — FedSiKD hosts them at the cluster
 edge, so a slow DEVICE delays only the student update's arrival.
 
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
       PYTHONPATH=src python examples/async_stragglers.py
 """
 import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+import jax
 
 from repro.data.synthetic import load_dataset
 from repro.fed.rounds import FedConfig, run_federated
 
 
 def main():
+    print(f"jax backend: {jax.default_backend()} ({len(jax.devices())} devices)")
     ds = load_dataset("mnist", small=True)
     common = dict(algorithm="fedsikd", engine="sharded", num_clients=16,
                   pack=2, alpha=0.1, rounds=6, local_epochs=1,

@@ -19,19 +19,21 @@ churn scenario:
   rebuilt — the compiled round program survives every event because the
   mesh is sized for the full client universe up front.
 
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
       PYTHONPATH=src python examples/client_churn.py
 """
 import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+import jax
 
 from repro.data.synthetic import load_dataset
 from repro.fed.rounds import FedConfig, run_federated
 
 
 def main():
+    print(f"jax backend: {jax.default_backend()} ({len(jax.devices())} devices)")
     ds = load_dataset("mnist", small=True)
     cfg = FedConfig(algorithm="fedsikd", engine="sharded",
                     num_clients=16, pack=2, alpha=1.0, rounds=5,

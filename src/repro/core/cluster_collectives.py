@@ -146,7 +146,11 @@ def packed_weighted_gather(tree, axis_name: str, table, *, pack: int):
             w = jax.lax.dynamic_slice_in_dim(table, base, pack, 0)  # (pack,S)
         else:
             w = jnp.broadcast_to(table[None, :], (pack, table.shape[0]))
-        return jnp.tensordot(w, g, axes=1).astype(x.dtype)
+        # full f32: the TPU's default precision rounds the operands to
+        # bf16, which would round every synced and aggregated parameter
+        return jnp.tensordot(w, g, axes=1,
+                             precision=jax.lax.Precision.HIGHEST
+                             ).astype(x.dtype)
 
     return jax.tree_util.tree_map(leaf, tree)
 

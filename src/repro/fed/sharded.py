@@ -39,10 +39,11 @@ Round-to-round state handling (slot gather/scatter of canonical per-cluster
 state) lives with the strategies in ``fed/algorithms/``; checkpoint/resume
 lives with the driver in ``fed/driver.py``.
 
-This runtime drives the paper's CNNs (or any pure fwd fn) and is exercised
-by tests/examples with ``--xla_force_host_platform_device_count``.  jax API
-drift (``jax.shard_map`` vs ``jax.experimental.shard_map``, mesh axis types)
-is absorbed by the small compat shims at the top.
+This runtime drives the paper's CNNs (or any pure fwd fn).  Tests and
+examples run it on CPU placeholder devices
+(``--xla_force_host_platform_device_count``); ``chip_smoke.py`` runs it on
+the TPU.  Every program is ``jax.shard_map(..., check_vma=False)``: the
+Pallas ``pallas_call`` inside the KD step has no replication rule.
 """
 from __future__ import annotations
 
@@ -67,25 +68,6 @@ from repro.launch.shardings import client_stack_specs, named
 from repro.optim import Optimizer, apply_updates, fedprox_penalty
 
 AXIS = CLIENT_AXIS
-
-
-# ------------------------------------------------------------ jax compat
-def shard_map(f, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions, with replication checking disabled
-    (the Pallas ``pallas_call`` primitive has no replication rule, so the
-    fused KD kernel requires ``check_rep=False`` / ``check_vma=False``)."""
-    try:                                     # jax >= 0.6: public API
-        sm = jax.shard_map
-    except AttributeError:                   # jax 0.4.x
-        from jax.experimental.shard_map import shard_map as sm_old
-        return sm_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except TypeError:                        # older keyword spelling
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
 
 
 def make_client_mesh(n_devices: int):
@@ -430,10 +412,10 @@ def make_packed_teacher_phase(mesh, pack: int, t_fwd: Callable,
         ts = cc.packed_teacher_sync(ts, AXIS, sync_mat, pack=pack)
         return tp, ts, _active_mean(loss, n_steps, AXIS)
 
-    return jax.jit(shard_map(
-        phase, mesh,
+    return jax.jit(jax.shard_map(
+        phase, mesh=mesh,
         in_specs=(P(AXIS),) * 6 + (P(),),
-        out_specs=(P(AXIS), P(AXIS), P()),
+        out_specs=(P(AXIS), P(AXIS), P()), check_vma=False,
     ), donate_argnums=(0, 1) if donate else ())
 
 
@@ -525,10 +507,10 @@ def make_packed_kd_round(mesh, pack: int, t_fwd: Callable, s_fwd: Callable,
                 _active_mean(t_loss, t_n, AXIS),
                 _active_mean(s_loss, s_n, AXIS))
 
-    return jax.jit(shard_map(
-        kd_round, mesh,
+    return jax.jit(jax.shard_map(
+        kd_round, mesh=mesh,
         in_specs=(P(AXIS),) * 12 + (P(), P()),
-        out_specs=(P(AXIS),) * 5 + (P(), P()),
+        out_specs=(P(AXIS),) * 5 + (P(), P()), check_vma=False,
     ), donate_argnums=(0, 1, 2, 3) if donate else ())
 
 
@@ -584,8 +566,8 @@ def make_packed_baseline_round(mesh, pack: int, fwd: Callable,
         p = cc.packed_weighted_mean(p, AXIS, agg_row, pack=pack)
         return p, p_local, s, _active_mean(loss, n_steps, AXIS)
 
-    return jax.jit(shard_map(
-        baseline_round, mesh,
+    return jax.jit(jax.shard_map(
+        baseline_round, mesh=mesh,
         in_specs=(P(AXIS),) * 6 + (P(), P()),
-        out_specs=(P(AXIS), P(AXIS), P(AXIS), P()),
+        out_specs=(P(AXIS), P(AXIS), P(AXIS), P()), check_vma=False,
     ), donate_argnums=(0, 1) if donate else ())

@@ -28,30 +28,34 @@ from jax.experimental import pallas as pl
 
 
 def _kernel(w_ref, s_ref, x_ref, o_ref, *, decay):
-    w = w_ref[...].astype(jnp.float32)               # (N,)
-    s = s_ref[...].astype(jnp.float32)               # (N,)
+    w = w_ref[...].astype(jnp.float32)               # (1, N)
+    s = s_ref[...].astype(jnp.float32)               # (1, N)
     wn = w * (1.0 + s) ** (-decay)
     wn = wn / jnp.sum(wn)                            # pad rows carry w=0
     x = x_ref[...].astype(jnp.float32)               # (N, BD)
-    o_ref[...] = wn @ x
+    # a 2-D (1, N) @ (N, BD) contraction: Mosaic has no 1-D-operand dot
+    o_ref[...] = jnp.dot(wn, x, precision=jax.lax.Precision.HIGHEST,
+                         preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("decay", "block_d", "interpret"))
 def fused_merge(x, w, s, *, decay: float = 0.0, block_d: int = 512,
                 interpret: bool = True):
     """x: (N,D), w: (N,), s: (N,) -> (D,) f32 decayed weighted mean.
-    D % block_d == 0 (pad at call site; pad N rows with w=0)."""
+    D % block_d == 0 (pad at call site; pad N rows with w=0).  The weights
+    and the output travel as (1, N) / (1, D) rows so every block is 2-D."""
     N, D = x.shape
     assert D % block_d == 0
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_kernel, decay=decay),
         grid=(D // block_d,),
         in_specs=[
-            pl.BlockSpec((N,), lambda i: (0,)),
-            pl.BlockSpec((N,), lambda i: (0,)),
+            pl.BlockSpec((1, N), lambda i: (0, 0)),
+            pl.BlockSpec((1, N), lambda i: (0, 0)),
             pl.BlockSpec((N, block_d), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((block_d,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((D,), jnp.float32),
+        out_specs=pl.BlockSpec((1, block_d), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, D), jnp.float32),
         interpret=interpret,
-    )(w, s, x)
+    )(w.reshape(1, N), s.reshape(1, N), x)
+    return out.reshape(D)

@@ -44,28 +44,30 @@ def _fwd_kernel(s_ref, t_ref, y_ref, loss_ref, stats_ref,
 
     s = s_ref[...].astype(jnp.float32)           # (BT, BV)
     t = t_ref[...].astype(jnp.float32)
-    y = y_ref[...]                               # (BT,)
+    y = y_ref[...]                               # (BT, 1)
 
     def online(m_ref, l_ref, x):
         m_old = m_ref[...]
-        m_new = jnp.maximum(m_old, jnp.max(x, axis=-1))
+        m_new = jnp.maximum(m_old, jnp.max(x, axis=-1, keepdims=True))
         scale = jnp.exp(m_old - m_new)
         l_ref[...] = l_ref[...] * scale + jnp.sum(
-            jnp.exp(x - m_new[:, None]), axis=-1)
+            jnp.exp(x - m_new), axis=-1, keepdims=True)
         m_ref[...] = m_new
         return m_new, scale
 
     # teacher @ tau — also rescale the weighted-difference accumulator
     m_new, scale = online(m_t, l_t, t / tau)
-    w = jnp.exp(t / tau - m_new[:, None])                       # unnorm p_t
-    u_acc[...] = u_acc[...] * scale + jnp.sum(w * (t - s) / tau, axis=-1)
+    w = jnp.exp(t / tau - m_new)                                # unnorm p_t
+    u_acc[...] = u_acc[...] * scale + jnp.sum(w * (t - s) / tau, axis=-1,
+                                              keepdims=True)
     online(m_s, l_s, s / tau)                                   # student @ tau
     online(m_1, l_1, s)                                         # student @ 1
 
     # label logit (appears in exactly one vocab block)
     cols = j * bv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    hit = cols == y[:, None]
-    picked[...] = picked[...] + jnp.sum(jnp.where(hit, s, 0.0), axis=-1)
+    hit = cols == y
+    picked[...] = picked[...] + jnp.sum(jnp.where(hit, s, 0.0), axis=-1,
+                                        keepdims=True)
 
     @pl.when(j == nv - 1)
     def _final():
@@ -76,10 +78,15 @@ def _fwd_kernel(s_ref, t_ref, y_ref, loss_ref, stats_ref,
         ce = logz_1 - picked[...]
         valid = (y >= 0).astype(jnp.float32)
         loss_ref[...] = ((1.0 - alpha) * ce + alpha * tau * tau * kl) * valid
-        stats_ref[...] = jnp.stack(
-            [logz_t, logz_s, logz_1], axis=-1)
+        stats_ref[:, 0:1] = logz_t
+        stats_ref[:, 1:2] = logz_s
+        stats_ref[:, 2:3] = logz_1
 
 
+# Per-token operands (labels, loss, g) travel as (T, 1) columns with
+# (block_t, 1) blocks: a 1-D (block_t,) block turns into an illegal
+# (1, block_t) tile when ``vmap`` adds a lane axis (the packed engine's
+# call), while (block_t, 1) stays (multiple of 8, full dim).
 @functools.partial(jax.jit, static_argnames=("tau", "alpha", "block_t",
                                              "block_v", "interpret"))
 def kd_loss_fwd(student_logits, teacher_logits, labels, *, tau: float = 2.0,
@@ -93,26 +100,28 @@ def kd_loss_fwd(student_logits, teacher_logits, labels, *, tau: float = 2.0,
     assert T % block_t == 0 and V % block_v == 0, (T, V, block_t, block_v)
     nt, nv = T // block_t, V // block_v
     grid = (nt, nv)
-    out = pl.pallas_call(
+    col = pl.BlockSpec((block_t, 1), lambda i, j: (i, 0))
+    loss, stats = pl.pallas_call(
         functools.partial(_fwd_kernel, tau=tau, alpha=alpha, nv=nv, bv=block_v),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_t, block_v), lambda i, j: (i, j)),
             pl.BlockSpec((block_t, block_v), lambda i, j: (i, j)),
-            pl.BlockSpec((block_t,), lambda i, j: (i,)),
+            col,
         ],
         out_specs=[
-            pl.BlockSpec((block_t,), lambda i, j: (i,)),
+            col,
             pl.BlockSpec((block_t, 3), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((T,), jnp.float32),
+            jax.ShapeDtypeStruct((T, 1), jnp.float32),
             jax.ShapeDtypeStruct((T, 3), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((block_t,), jnp.float32) for _ in range(8)],
+        scratch_shapes=[pltpu.VMEM((block_t, 1), jnp.float32)
+                        for _ in range(8)],
         interpret=interpret,
-    )(student_logits, teacher_logits, labels)
-    return out
+    )(student_logits, teacher_logits, labels.reshape(T, 1))
+    return loss.reshape(T), stats
 
 
 def _bwd_kernel(s_ref, t_ref, y_ref, stats_ref, g_ref, ds_ref,
@@ -123,18 +132,18 @@ def _bwd_kernel(s_ref, t_ref, y_ref, stats_ref, g_ref, ds_ref,
     j = pl.program_id(1)
     s = s_ref[...].astype(jnp.float32)
     t = t_ref[...].astype(jnp.float32)
-    y = y_ref[...]
-    logz_t = stats_ref[..., 0]
-    logz_s = stats_ref[..., 1]
-    logz_1 = stats_ref[..., 2]
-    p1 = jnp.exp(s - logz_1[:, None])
-    ps = jnp.exp(s / tau - logz_s[:, None])
-    pt = jnp.exp(t / tau - logz_t[:, None])
+    y = y_ref[...]                               # (BT, 1)
+    logz_t = stats_ref[:, 0:1]
+    logz_s = stats_ref[:, 1:2]
+    logz_1 = stats_ref[:, 2:3]
+    p1 = jnp.exp(s - logz_1)
+    ps = jnp.exp(s / tau - logz_s)
+    pt = jnp.exp(t / tau - logz_t)
     cols = j * bv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    onehot = (cols == y[:, None]).astype(jnp.float32)
-    valid = (y >= 0).astype(jnp.float32)[:, None]
+    onehot = (cols == y).astype(jnp.float32)
+    valid = (y >= 0).astype(jnp.float32)
     ds = (1.0 - alpha) * (p1 - onehot) + (alpha * tau) * (ps - pt)
-    ds_ref[...] = (g_ref[...][:, None] * ds * valid).astype(ds_ref.dtype)
+    ds_ref[...] = (g_ref[...] * ds * valid).astype(ds_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("tau", "alpha", "block_t",
@@ -144,17 +153,19 @@ def kd_loss_bwd(student_logits, teacher_logits, labels, stats, g, *,
                 block_v: int = 512, interpret: bool = True):
     T, V = student_logits.shape
     nt, nv = T // block_t, V // block_v
+    col = pl.BlockSpec((block_t, 1), lambda i, j: (i, 0))
     return pl.pallas_call(
         functools.partial(_bwd_kernel, tau=tau, alpha=alpha, bv=block_v),
         grid=(nt, nv),
         in_specs=[
             pl.BlockSpec((block_t, block_v), lambda i, j: (i, j)),
             pl.BlockSpec((block_t, block_v), lambda i, j: (i, j)),
-            pl.BlockSpec((block_t,), lambda i, j: (i,)),
+            col,
             pl.BlockSpec((block_t, 3), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_t,), lambda i, j: (i,)),
+            col,
         ],
         out_specs=pl.BlockSpec((block_t, block_v), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((T, V), student_logits.dtype),
         interpret=interpret,
-    )(student_logits, teacher_logits, labels, stats, g)
+    )(student_logits, teacher_logits, labels.reshape(T, 1), stats,
+      g.reshape(T, 1))

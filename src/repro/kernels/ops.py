@@ -15,17 +15,15 @@ wrappers hide.  Every wrapper provides:
 - **custom_vjp wiring** — ``kd_distillation_loss`` pairs the forward kernel
   with the analytic blockwise backward kernel instead of differentiating
   through the online-softmax recurrence.
-- **Interpret-mode fallback** — ``interpret=None`` (the default) resolves
-  via backend detection: TPU runs the compiled Pallas kernel, any other
-  backend (this CPU container included) runs the kernel in Pallas interpret
-  mode, which is numerically identical but is a correctness harness, not a
-  performance path (benchmarks/kernels_bench.py measures the jnp reference
-  on CPU for that reason).
+- **Backend-resolved interpret mode** — ``interpret=None`` (the default)
+  resolves via backend detection: TPU runs the compiled Pallas kernel, the
+  CPU runs the kernel in Pallas interpret mode (numerically identical, a
+  correctness harness, not a performance path), and any other backend
+  raises instead of quietly interpreting.
 
 All wrappers are safe under ``jit``, ``grad``, ``vmap``, ``lax.scan`` and
-``shard_map`` — note that ``shard_map`` callers must disable replication
-checking (``check_rep=False`` / ``check_vma=False``): ``pallas_call`` has no
-replication rule (``repro.fed.sharded.shard_map`` does this for you).
+``jax.shard_map`` — ``shard_map`` callers pass ``check_vma=False``:
+``pallas_call`` has no replication rule.
 """
 from __future__ import annotations
 
@@ -43,7 +41,14 @@ NEG = -1e30
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    """True on the CPU (Pallas interpret mode), False on the TPU; any other
+    backend has no compiled kernels and no business interpreting them."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas kernels compile for the TPU and interpret on the CPU; "
+            f"backend {backend!r} is neither (pass interpret= explicitly)")
+    return backend == "cpu"
 
 
 def _pad_to(x, axis, mult, value):

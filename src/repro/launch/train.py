@@ -25,6 +25,7 @@ from repro.data.pipeline import token_stream
 from repro.data.synthetic import load_dataset
 from repro.fed.rounds import FedConfig, run_federated
 from repro.launch import steps as st
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import encdec as ed
 from repro.models import transformer as tf
 
@@ -228,6 +229,7 @@ def main():
     lm.add_argument("--ckpt", default=None)
 
     args = ap.parse_args()
+    enable_compile_cache()
     if args.mode == "fl":
         run_fl(args)
     else:

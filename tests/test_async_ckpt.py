@@ -131,10 +131,9 @@ while True:                      # stream checkpoints until SIGKILLed
 
 
 def test_sigkill_mid_stream_leaves_only_complete_checkpoints(tmp_path):
-    env = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
-           "JAX_PLATFORMS": "cpu"}
+    from _subproc import ENV
     p = subprocess.Popen([sys.executable, "-c", _KILL_CHILD, str(tmp_path)],
-                         stdout=subprocess.PIPE, text=True, env=env)
+                         stdout=subprocess.PIPE, text=True, env=ENV)
     try:
         assert p.stdout.readline().strip() == "READY"
         deadline = time.time() + 30

@@ -6,6 +6,7 @@ import sys
 import textwrap
 
 import pytest
+from _subproc import ENV
 
 from repro.launch.roofline import Roofline, _shape_bytes, collective_bytes
 
@@ -91,7 +92,5 @@ _SMALL_MESH_SCRIPT = textwrap.dedent("""
 @pytest.mark.slow
 def test_small_mesh_dryrun_all_families():
     r = subprocess.run([sys.executable, "-c", _SMALL_MESH_SCRIPT],
-                       capture_output=True, text=True, timeout=1200,
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                            "HOME": "/root"})
+                       capture_output=True, text=True, timeout=1200, env=ENV)
     assert "DRYRUN-OK" in r.stdout, r.stdout[-2000:] + r.stderr[-4000:]

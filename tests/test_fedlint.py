@@ -6,7 +6,9 @@ import subprocess
 import sys
 from pathlib import Path
 
-REPO = Path(__file__).resolve().parents[1]
+from _subproc import ENV
+
+REPO =Path(__file__).resolve().parents[1]
 if str(REPO) not in sys.path:           # `tools` lives at the repo root
     sys.path.insert(0, str(REPO))
 
@@ -157,7 +159,7 @@ def test_shipped_tree_is_clean():
 
 
 def test_cli_exit_codes_and_json_report(tmp_path):
-    env = {"PATH": "/usr/bin:/bin", "HOME": "/root"}
+    env = ENV
     clean = subprocess.run(
         [sys.executable, "-m", "tools.fedlint", "src/repro",
          "--json", str(tmp_path / "report.json")],

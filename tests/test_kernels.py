@@ -70,6 +70,18 @@ def test_kd_batched_shapes():
     np.testing.assert_allclose(float(loss), float(lref), rtol=1e-5)
 
 
+def test_interpret_default_follows_backend(monkeypatch):
+    """CPU interprets, TPU compiles, anything else raises rather than
+    quietly interpreting on a device it was not built for."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert ops._interpret_default() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops._interpret_default() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops._interpret_default()
+
+
 # --------------------------------------------------------- flash attention
 @pytest.mark.parametrize("B,H,KVH,T,S,hd", [
     (1, 4, 4, 64, 64, 32),

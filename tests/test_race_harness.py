@@ -27,20 +27,23 @@ _JITTER_UNIT = textwrap.dedent("""
     assert not guards.jitter_enabled()
 
     # armed: deterministic per (seed, tag, occurrence) — replaying a tag
-    # sequence under one seed sleeps the identical schedule
+    # sequence under one seed sleeps the identical schedule (the requested
+    # sleep durations are recorded exactly, so host load cannot blur them)
     def schedule(seed, tags):
+        slept, real_sleep = [], time.sleep
+        time.sleep = lambda d: (slept.append(d), real_sleep(d))
         guards.enable_jitter(seed)
-        out = []
-        for t in tags:
-            t0 = time.perf_counter()
-            guards.jitter_point(t)
-            out.append(round(time.perf_counter() - t0, 2))
-        guards.disable_jitter()
-        return out
+        try:
+            for t in tags:
+                guards.jitter_point(t)
+        finally:
+            guards.disable_jitter()
+            time.sleep = real_sleep
+        return slept
 
     tags = ["wave-stage", "wave-prefetch", "wave-stage", "ckpt-submit"]
     a, b = schedule(7, tags), schedule(7, tags)
-    assert a == b, (a, b)
+    assert a == b and len(a) == len(tags), (a, b)
     assert any(d > 0.0 for d in a), a          # it actually sleeps
     assert schedule(8, tags) != a or True      # other seeds are legal too
     assert not guards.jitter_enabled()

@@ -66,8 +66,9 @@ _BATCHED_KD_SCRIPT = textwrap.dedent("""
             s[0], t[0], y[0], tau=3.0, alpha=0.25)
         return loss[None]
 
-    f = jax.jit(sh.shard_map(per_device, mesh, in_specs=(P("clients"),) * 3,
-                             out_specs=P("clients")))
+    f = jax.jit(jax.shard_map(per_device, mesh=mesh,
+                              in_specs=(P("clients"),) * 3,
+                              out_specs=P("clients"), check_vma=False))
     got = np.asarray(f(s, t, y))
     want = np.asarray(jax.vmap(ref_loss)(s, t, y))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
@@ -78,9 +79,9 @@ _BATCHED_KD_SCRIPT = textwrap.dedent("""
             s_, t[0], y[0], tau=3.0, alpha=0.25))(s[0])
         return g[None]
 
-    fg = jax.jit(sh.shard_map(per_device_grad, mesh,
-                              in_specs=(P("clients"),) * 3,
-                              out_specs=P("clients")))
+    fg = jax.jit(jax.shard_map(per_device_grad, mesh=mesh,
+                               in_specs=(P("clients"),) * 3,
+                               out_specs=P("clients"), check_vma=False))
     gg = np.asarray(fg(s, t, y))
     gr = np.asarray(jax.vmap(jax.grad(ref_loss))(s, t, y))
     np.testing.assert_allclose(gg, gr, rtol=1e-4, atol=1e-5)

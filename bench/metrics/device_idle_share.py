@@ -1,0 +1,9 @@
+"""1 - (union of the device operations' intervals / the traced window),
+in %, averaged over the chips."""
+
+
+def read(ctx):
+    t = ctx["trace"]
+    if t is None or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])

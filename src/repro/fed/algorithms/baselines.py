@@ -236,7 +236,7 @@ class PackedBaseline(_BaselineBase):
         the clustered-KD engines')."""
         return self.sh.slot_client_keys(
             jax.random.fold_in(self.key,
-                               jax.device_put(np.uint32(40_000 + rnd))),
+                               self.sh.to_device(np.uint32(40_000 + rnd))),
             plan)
 
     def warm_async_merge(self):
@@ -283,20 +283,26 @@ class PackedBaseline(_BaselineBase):
             if not wp.active.any():
                 continue
             with perf.span("stage"):
-                xs, ys = self.stager.stage(wp)
-                p_s, s_s = self._prep(self.global_params)
+                with perf.span("stager"):
+                    xs, ys = self.stager.stage(wp)
+                with perf.span("prep"):
+                    p_s, s_s = self._prep(self.global_params)
             with perf.span("compute"):
-                # device_put: explicit transfers, legal under the guards
-                n_w = wp.steps_for(self.steps_all)
-                p_s, p_local, _s_s, loss = self.round_fn(
-                    p_s, s_s, xs, ys, jax.device_put(n_w),
-                    self._slot_keys(rnd, wp),
-                    jax.device_put(np.ascontiguousarray(
-                        row[w * ws:(w + 1) * ws])),
-                    self.global_params)
+                with perf.span("dispatch"):
+                    # explicit transfers (sh.to_device), legal under the guards
+                    n_w = wp.steps_for(self.steps_all)
+                    p_s, p_local, _s_s, loss = self.round_fn(
+                        p_s, s_s, xs, ys, sh.to_device(n_w),
+                        self._slot_keys(rnd, wp),
+                        sh.to_device(np.ascontiguousarray(
+                            row[w * ws:(w + 1) * ws])),
+                        self.global_params)
                 if w + 1 < n_waves:
                     self.stager.prefetch(plan.wave(w + 1))
-                loss = float(loss)   # block for honest timing attribution
+                # the loss read blocks until the wave's program ends
+                with perf.span("sync"):
+                    perf.count("host_syncs")
+                    loss = float(loss)
                 losses.append((loss, int((n_w > 0).sum())))
             with perf.span("aggregate"):
                 # every slot holds the wave's partial aggregate after the

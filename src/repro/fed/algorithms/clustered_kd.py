@@ -566,15 +566,15 @@ class ShardedClusteredKD(_ClusteredKDBase):
                 src[row[s]] = s
         refreshed = src >= 0
         safe = np.where(refreshed, src, 0)
-        return jax.device_put(refreshed), jax.device_put(safe)
+        return self.sh.to_device(refreshed), self.sh.to_device(safe)
 
     def _student_keys(self, salt, plan):
         """Per-slot training keys, folded by client id (sh.slot_client_keys:
         stable under slot re-assignment across rounds).  The salt lands on
         device explicitly so the eager fold_in stays guard-legal."""
-        return self.sh.slot_client_keys(
-            jax.random.fold_in(self.key, jax.device_put(np.uint32(salt))),
-            plan)
+        salt = self.sh.to_device(np.uint32(salt))
+        return self.sh.slot_client_keys(jax.random.fold_in(self.key, salt),
+                                        plan)
 
     def _teacher_keys(self, salt, plan):
         """Teacher-step keys.  Leader mode: slots of a cluster share one key
@@ -582,7 +582,8 @@ class ShardedClusteredKD(_ClusteredKDBase):
         batches stay bitwise in sync between sync collectives).  Cluster
         mode: per-client keys, offset 10_000 to stay disjoint from the
         student stream (each slot steps on its own client's shard anyway)."""
-        base = jax.random.fold_in(self.key, jax.device_put(np.uint32(salt)))
+        base = jax.random.fold_in(self.key,
+                                  self.sh.to_device(np.uint32(salt)))
         if self.cfg.teacher_data == "leader":
             return self.sh.slot_cluster_keys(base, plan)
         return self.sh.slot_client_keys(base, plan, offset=10_000)
@@ -692,35 +693,41 @@ class ShardedClusteredKD(_ClusteredKDBase):
             if not wp.active.any():
                 continue
             with perf.span("stage"):
-                tx, ty, sx, sy = self.stager.stage(wp)
-                tp_s, ts_s, sp_s, ss_s = self._prep(
-                    tp0, ts0, sp_start,
-                    jax.device_put(self._teacher_row(wp)))
+                with perf.span("stager"):
+                    tx, ty, sx, sy = self.stager.stage(wp)
+                with perf.span("prep"):
+                    tp_s, ts_s, sp_s, ss_s = self._prep(
+                        tp0, ts0, sp_start,
+                        sh.to_device(self._teacher_row(wp)))
             with perf.span("compute"):
-                # disjoint even/odd salts keep teacher and student PRNG
-                # streams from colliding on clients whose id equals their
-                # cluster index (device_put: explicit transfers, legal
-                # under the guards); keys fold client/cluster ids, so a
-                # client's stream is invariant to its wave placement
-                t_n = wp.steps_for(self.t_steps_all)
-                s_n = wp.steps_for(self.s_steps_all)
-                (tp_s, ts_s, sp_s, sp_local, _ss_s, t_loss,
-                 s_loss) = self.round_fn(
-                    tp_s, ts_s, sp_s, ss_s, tx, ty,
-                    jax.device_put(t_n), sx, sy,
-                    jax.device_put(s_n),
-                    self._teacher_keys(2 * rnd, wp),
-                    self._student_keys(2 * rnd + 1, wp),
-                    jax.device_put(wp.sync_matrix()),
-                    jax.device_put(np.ascontiguousarray(
-                        row[w * ws:(w + 1) * ws])))
+                with perf.span("dispatch"):
+                    # disjoint even/odd salts keep teacher and student PRNG
+                    # streams from colliding on clients whose id equals
+                    # their cluster index (sh.to_device: explicit transfers,
+                    # legal under the guards); keys fold client/cluster
+                    # ids, so a client's stream is invariant to its wave
+                    # placement
+                    t_n = wp.steps_for(self.t_steps_all)
+                    s_n = wp.steps_for(self.s_steps_all)
+                    (tp_s, ts_s, sp_s, sp_local, _ss_s, t_loss,
+                     s_loss) = self.round_fn(
+                        tp_s, ts_s, sp_s, ss_s, tx, ty,
+                        sh.to_device(t_n), sx, sy, sh.to_device(s_n),
+                        self._teacher_keys(2 * rnd, wp),
+                        self._student_keys(2 * rnd + 1, wp),
+                        sh.to_device(wp.sync_matrix()),
+                        sh.to_device(np.ascontiguousarray(
+                            row[w * ws:(w + 1) * ws])))
                 if w + 1 < n_waves:
                     # double-buffer: wave w+1's host gather + device_put
                     # run behind wave w's (async-dispatched) compute
                     self.stager.prefetch(plan.wave(w + 1))
-                # block on the scalars so timing attribution stays honest
-                losses.append((float(t_loss), (t_n > 0).sum(),
-                               float(s_loss), (s_n > 0).sum()))
+                # the two loss reads block until the wave's program ends:
+                # wave w+1 is not dispatched before then
+                with perf.span("sync"):
+                    perf.count("host_syncs", 2)
+                    losses.append((float(t_loss), (t_n > 0).sum(),
+                                   float(s_loss), (s_n > 0).sum()))
             with perf.span("aggregate"):
                 refreshed, safe = self._scatter_src(wp)
                 tp_acc, ts_acc, sp0_w = self._finish(

@@ -10,6 +10,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro import perf
 from repro.core.distill import distillation_loss, softmax_cross_entropy
 from repro.kernels import ops
 from repro.optim import Optimizer, apply_updates, fedprox_penalty
@@ -70,9 +71,11 @@ def make_steps(fwd: Callable, opt: Optimizer, *, kd_temperature: float = 2.0,
 
     @functools.partial(jax.jit, static_argnames=())
     def eval_batch(params, x, y):
-        logits = fwd(params, x, train=False, key=None)
-        loss = softmax_cross_entropy(logits, y)
-        acc = jnp.mean((jnp.argmax(logits, -1) == y).astype(jnp.float32))
+        with jax.named_scope("eval_forward"):
+            logits = fwd(params, x, train=False, key=None)
+            loss = softmax_cross_entropy(logits, y)
+            acc = jnp.mean(
+                (jnp.argmax(logits, -1) == y).astype(jnp.float32))
         return acc, loss
 
     return {"ce": ce_step, "prox": prox_step, "make_distill": make_distill_step,
@@ -80,13 +83,20 @@ def make_steps(fwd: Callable, opt: Optimizer, *, kd_temperature: float = 2.0,
 
 
 def evaluate(eval_batch, params, x, y, batch_size: int = 256):
-    """Dataset accuracy/loss via batched eval (last partial batch included)."""
+    """Dataset accuracy/loss via batched eval (last partial batch included).
+
+    Per batch, ``perf`` records ``eval_step`` (slice, implicit transfer of
+    the host batch, dispatch) and ``sync`` (the two blocking reads)."""
     accs, losses, ns = [], [], []
     for s in range(0, len(y), batch_size):
-        xa, ya = x[s:s + batch_size], y[s:s + batch_size]
-        a, l = eval_batch(params, xa, ya)
-        accs.append(float(a) * len(ya))
-        losses.append(float(l) * len(ya))
+        with perf.span("eval_step"):
+            xa, ya = x[s:s + batch_size], y[s:s + batch_size]
+            perf.count_bytes("h2d_bytes", xa, ya)
+            a, l = eval_batch(params, xa, ya)
+        with perf.span("sync"):
+            perf.count("host_syncs", 2)
+            accs.append(float(a) * len(ya))
+            losses.append(float(l) * len(ya))
         ns.append(len(ya))
     n = sum(ns)
     return sum(accs) / n, sum(losses) / n

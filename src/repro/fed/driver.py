@@ -324,7 +324,8 @@ class RoundDriver:
                     hot = (guards.no_implicit_transfers() if guarded
                            else contextlib.nullcontext())
                     with hot:
-                        plan = alg.scheduler.plan(rnd)
+                        with perf.span("plan"):
+                            plan = alg.scheduler.plan(rnd)
                         if cfg.prefetch and rnd < cfg.rounds \
                                 and (lc is None
                                      or not lc.event(rnd + 1).recluster):
@@ -333,7 +334,8 @@ class RoundDriver:
                             # functions of (seed, round); a lifecycle event
                             # round is skipped — its plan only exists after
                             # apply_lifecycle rebuilds the scheduler)
-                            alg.prefetch(alg.scheduler.plan(rnd + 1))
+                            with perf.span("prefetch"):
+                                alg.prefetch(alg.scheduler.plan(rnd + 1))
                         if self.buffer is not None:
                             arrivals, dropped = self.buffer.pop_due(rnd)
                             alg.arrivals = tuple(arrivals)

@@ -107,7 +107,15 @@ def client_step_counts(shards, batch_size: int, epochs: int) -> np.ndarray:
                        for sh in shards], np.int32)
 
 
-def stage_on_slots(mesh, plan: RoundPlan, *arrays, row_maps=None):
+def to_device(x):
+    """``jax.device_put`` of a host array (an explicit transfer, legal under
+    the guards), its bytes counted as ``perf``'s ``h2d_bytes``."""
+    perf.count_bytes("h2d_bytes", x)
+    return jax.device_put(x)
+
+
+def stage_on_slots(mesh, plan: RoundPlan, *arrays, row_maps=None,
+                   round_id=None):
     """Row-gather this round's participants onto mesh slots and place the
     (S, ...) stacks with the packed client-axis sharding (idle slots carry
     row 0; they run zero steps).
@@ -121,15 +129,20 @@ def stage_on_slots(mesh, plan: RoundPlan, *arrays, row_maps=None):
     translates the plan's CLIENT ids into each array's row space — how a
     100k-virtual-client universe stages through base stacks that only
     materialise the data pool (``data.pipeline.ClientStore.row_of``), and
-    how the KD teacher feed maps a slot to its cluster LEADER's rows."""
-    cid = np.where(plan.active, plan.slot_client, 0)
-    maps = (None,) * len(arrays) if row_maps is None else row_maps
-    stacks = tuple(
-        np.ascontiguousarray(
-            np.asarray(a)[cid if m is None else np.asarray(m)[cid]])
-        for a, m in zip(arrays, maps))
-    return jax.device_put(stacks, named(mesh, client_stack_specs(
-        stacks, mesh, axis=AXIS)))
+    how the KD teacher feed maps a slot to its cluster LEADER's rows.
+
+    ``perf`` records a ``gather`` span and the stacks' ``h2d_bytes``, into
+    round ``round_id`` when given (a prefetch thread's submission token)."""
+    with perf.span("gather", round_id=round_id):
+        cid = np.where(plan.active, plan.slot_client, 0)
+        maps = (None,) * len(arrays) if row_maps is None else row_maps
+        stacks = tuple(
+            np.ascontiguousarray(
+                np.asarray(a)[cid if m is None else np.asarray(m)[cid]])
+            for a, m in zip(arrays, maps))
+        perf.count_bytes("h2d_bytes", *stacks, round_id=round_id)
+        return jax.device_put(stacks, named(mesh, client_stack_specs(
+            stacks, mesh, axis=AXIS)))
 
 
 class SlotStager:
@@ -170,11 +183,13 @@ class SlotStager:
             return
         self._drop_pending()
         box = {}
+        token = perf.round_token()
 
         def work():
             guards.jitter_point("slot-prefetch")
             try:
-                box["staged"] = stage_on_slots(self.mesh, plan, *self.arrays)
+                box["staged"] = stage_on_slots(self.mesh, plan, *self.arrays,
+                                               round_id=token)
             except Exception as e:   # pragma: no cover - surfaced via fallback
                 box["error"] = e
 
@@ -227,9 +242,9 @@ class WaveStager:
         self._staged: dict[bytes, tuple] = {}    # insertion-ordered LRU
         self._pending: dict[bytes, tuple] = {}   # key -> (thread, box)
 
-    def _gather(self, plan: RoundPlan):
+    def _gather(self, plan: RoundPlan, round_id=None):
         return stage_on_slots(self.mesh, plan, *self.arrays,
-                              row_maps=self.row_maps)
+                              row_maps=self.row_maps, round_id=round_id)
 
     def _put(self, key: bytes, staged):
         self._staged[key] = staged
@@ -273,12 +288,13 @@ class WaveStager:
         if key in self._staged or key in self._pending:
             return
         box: dict = {}
+        token = perf.round_token()
 
         def work():
             guards.jitter_point("wave-prefetch")
             t0 = time.perf_counter()
             try:
-                box["staged"] = self._gather(plan)
+                box["staged"] = self._gather(plan, round_id=token)
             except Exception as e:  # pragma: no cover - raised on sync retry
                 box["error"] = e
             box["dt"] = time.perf_counter() - t0
@@ -307,9 +323,10 @@ def slot_client_keys(base, plan: RoundPlan, *, offset: int = 0):
     key streams stay stable under slot re-assignment across rounds (idle
     slots fold client 0; they never train)."""
     cid = np.where(plan.active, plan.slot_client, 0)
-    # device_put, not jnp.asarray: the EXPLICIT transfer stays legal under
-    # guards.no_implicit_transfers() (same uint32 wrap-around semantics)
-    return _fold_keys(base, jax.device_put(
+    # to_device (a device_put), not jnp.asarray: the EXPLICIT transfer
+    # stays legal under guards.no_implicit_transfers() (same uint32
+    # wrap-around semantics)
+    return _fold_keys(base, to_device(
         (offset + cid.astype(np.int64)).astype(np.uint32)))
 
 
@@ -318,7 +335,7 @@ def slot_cluster_keys(base, plan: RoundPlan):
     of a cluster share one key (identical batches + identical dropout masks
     keep teacher replicas bitwise in sync between sync collectives)."""
     kidx = np.where(plan.active, plan.slot_cluster, 0)
-    return _fold_keys(base, jax.device_put(kidx.astype(np.uint32)))
+    return _fold_keys(base, to_device(kidx.astype(np.uint32)))
 
 
 @functools.partial(jax.jit, static_argnums=1)
@@ -352,8 +369,9 @@ def _masked_scan_steps(step_fn, carry, xs, ys, n_steps):
         x, y, i = batch
         new_carry, loss = step_fn(carry, (x, y, i))
         live = i < n_steps
-        carry = jax.tree_util.tree_map(
-            lambda new, old: jnp.where(live, new, old), new_carry, carry)
+        with jax.named_scope("masked_carry"):
+            carry = jax.tree_util.tree_map(
+                lambda new, old: jnp.where(live, new, old), new_carry, carry)
         return carry, jnp.where(live, loss, 0.0)
 
     carry, losses = jax.lax.scan(step, carry, (xs, ys, idx))
@@ -407,9 +425,11 @@ def make_packed_teacher_phase(mesh, pack: int, t_fwd: Callable,
             step = _make_teacher_step(t_fwd, t_opt, rng)
             return _masked_scan_steps(step, (tp, ts), xs, ys, n)
 
-        (tp, ts), loss = jax.vmap(lane)(tp, ts, xs, ys, n_steps, rng)
-        tp = cc.packed_teacher_sync(tp, AXIS, sync_mat, pack=pack)
-        ts = cc.packed_teacher_sync(ts, AXIS, sync_mat, pack=pack)
+        with jax.named_scope("teacher_phase"):
+            (tp, ts), loss = jax.vmap(lane)(tp, ts, xs, ys, n_steps, rng)
+        with jax.named_scope("cross_lane"):
+            tp = cc.packed_teacher_sync(tp, AXIS, sync_mat, pack=pack)
+            ts = cc.packed_teacher_sync(ts, AXIS, sync_mat, pack=pack)
         return tp, ts, _active_mean(loss, n_steps, AXIS)
 
     return jax.jit(jax.shard_map(
@@ -468,9 +488,11 @@ def make_packed_kd_round(mesh, pack: int, t_fwd: Callable, s_fwd: Callable,
             step = _make_teacher_step(t_fwd, t_opt, rng)
             return _masked_scan_steps(step, (tp, ts), xs, ys, n)
 
-        (tp, ts), t_loss = jax.vmap(t_lane)(tp, ts, tx, ty, t_n, t_rng)
-        tp = cc.packed_teacher_sync(tp, AXIS, sync_mat, pack=pack)
-        ts = cc.packed_teacher_sync(ts, AXIS, sync_mat, pack=pack)
+        with jax.named_scope("teacher_phase"):
+            (tp, ts), t_loss = jax.vmap(t_lane)(tp, ts, tx, ty, t_n, t_rng)
+        with jax.named_scope("cross_lane"):
+            tp = cc.packed_teacher_sync(tp, AXIS, sync_mat, pack=pack)
+            ts = cc.packed_teacher_sync(ts, AXIS, sync_mat, pack=pack)
 
         # ---- 3: student distillation against the synced cluster teacher
         def s_lane(sp, ss, xs, ys, n, rng, tp):
@@ -496,13 +518,16 @@ def make_packed_kd_round(mesh, pack: int, t_fwd: Callable, s_fwd: Callable,
 
             return _masked_scan_steps(s_step, (sp, ss), xs, ys, n)
 
-        (sp, ss), s_loss = jax.vmap(s_lane)(sp, ss, sx, sy, s_n, s_rng, tp)
+        with jax.named_scope("student_kd"):
+            (sp, ss), s_loss = jax.vmap(s_lane)(sp, ss, sx, sy, s_n, s_rng,
+                                                tp)
 
         # ---- 4: grouped aggregation (plan-weighted mean -> every slot);
         # the pre-aggregation per-slot students ride along so straggler
         # lanes can be buffered host-side without a second program
         sp_local = sp
-        sp = cc.packed_weighted_mean(sp, AXIS, agg_row, pack=pack)
+        with jax.named_scope("cross_lane"):
+            sp = cc.packed_weighted_mean(sp, AXIS, agg_row, pack=pack)
         return (tp, ts, sp, sp_local, ss,
                 _active_mean(t_loss, t_n, AXIS),
                 _active_mean(s_loss, s_n, AXIS))
@@ -563,7 +588,8 @@ def make_packed_baseline_round(mesh, pack: int, fwd: Callable,
 
         (p, s), loss = jax.vmap(lane)(p, s, xs, ys, n_steps, rng)
         p_local = p
-        p = cc.packed_weighted_mean(p, AXIS, agg_row, pack=pack)
+        with jax.named_scope("cross_lane"):
+            p = cc.packed_weighted_mean(p, AXIS, agg_row, pack=pack)
         return p, p_local, s, _active_mean(loss, n_steps, AXIS)
 
     return jax.jit(jax.shard_map(

@@ -96,5 +96,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qf, kf, vf)
     return out.reshape(B, H, T, hd)

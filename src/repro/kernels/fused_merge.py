@@ -57,5 +57,6 @@ def fused_merge(x, w, s, *, decay: float = 0.0, block_d: int = 512,
         out_specs=pl.BlockSpec((1, block_d), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, D), jnp.float32),
         interpret=interpret,
+        name="fused_merge",
     )(w.reshape(1, N), s.reshape(1, N), x)
     return out.reshape(D)

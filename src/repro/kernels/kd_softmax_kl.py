@@ -120,6 +120,7 @@ def kd_loss_fwd(student_logits, teacher_logits, labels, *, tau: float = 2.0,
         scratch_shapes=[pltpu.VMEM((block_t, 1), jnp.float32)
                         for _ in range(8)],
         interpret=interpret,
+        name="kd_loss_fwd",
     )(student_logits, teacher_logits, labels.reshape(T, 1))
     return loss.reshape(T), stats
 
@@ -167,5 +168,6 @@ def kd_loss_bwd(student_logits, teacher_logits, labels, stats, g, *,
         out_specs=pl.BlockSpec((block_t, block_v), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((T, V), student_logits.dtype),
         interpret=interpret,
+        name="kd_loss_bwd",
     )(student_logits, teacher_logits, labels.reshape(T, 1), stats,
       g.reshape(T, 1))

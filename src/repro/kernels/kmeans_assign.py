@@ -46,4 +46,5 @@ def kmeans_assign(x, cents, *, block_n: int = 128, interpret: bool = True):
             jax.ShapeDtypeStruct((N,), jnp.float32),
         ],
         interpret=interpret,
+        name="kmeans_assign",
     )(x, cents)

@@ -13,7 +13,8 @@ Lifecycle (driven by ``RoundDriver.run``):
    construction, the ``RoundScheduler``, staged data.  Must populate
    ``scheduler`` (the participation policy the driver plans with),
    ``labels`` (cluster assignment for the run fingerprint, or None) and
-   ``history_extras()``'s inputs.  Runs on resume too — it must be
+   ``history_extras()``'s inputs, and stages the test set on the device
+   (``stage_test_set``) for ``eval``.  Runs on resume too — it must be
    deterministic, so recomputed clustering catches silent data/config
    drift between save and resume.
 2. ``warmup()`` — pre-round establishment work whose RESULT is part of the
@@ -63,6 +64,7 @@ import numpy as np
 
 from repro.core import aggregation as agg
 from repro.data.pipeline import ClientShard
+from repro.fed.client import stage_test_set
 from repro.fed.lifecycle import ClientLifecycle, LifecycleEvent
 from repro.fed.schedule import RoundPlan, RoundScheduler
 
@@ -76,6 +78,8 @@ class Algorithm:
     # populated by setup():
     scheduler: RoundScheduler
     labels: Optional[np.ndarray] = None
+    mesh = None              # the packed engines' client mesh
+    test_set: tuple = ()     # stage_test_set's [n_batches, batch, ...] pair
     # set by the driver before setup():
     progress: bool = False
     lifecycle: Optional[ClientLifecycle] = None
@@ -85,6 +89,12 @@ class Algorithm:
 
     def setup(self, ds, shards: list[ClientShard], cfg, key) -> None:
         raise NotImplementedError
+
+    def stage_test_set(self, ds) -> None:
+        """Put ``ds``'s test set on the device once for every ``eval``
+        (``client.stage_test_set``), replicated over the strategy's
+        ``mesh`` where it has one."""
+        self.test_set = stage_test_set(ds.x_test, ds.y_test, self.mesh)
 
     def warmup(self) -> None:
         """Pre-round establishment (checkpointed state; skipped on resume)."""

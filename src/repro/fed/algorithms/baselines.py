@@ -60,6 +60,7 @@ class _BaselineBase(Algorithm):
         self.global_params = t_init(key)
         self.sizes = np.asarray(shards.sizes)
         self._setup_engine()
+        self.stage_test_set(ds)
 
     def _roster_labels(self, active) -> np.ndarray:
         """Single pseudo-cluster label array over the CURRENT roster (-1
@@ -87,8 +88,7 @@ class _BaselineBase(Algorithm):
         pass
 
     def eval(self):
-        return evaluate(self.steps["eval"], self.global_params,
-                        self.ds.x_test, self.ds.y_test)
+        return evaluate(self.steps["eval"], self.global_params, self.test_set)
 
     def checkpoint_arrays(self):
         # the roster rides the checkpoint: a resume past a lifecycle event

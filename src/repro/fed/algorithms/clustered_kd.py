@@ -166,6 +166,7 @@ class _ClusteredKDBase(Algorithm):
         self.t_model = make_model(ds.name, student=False)
         self.s_model = make_model(ds.name, student=True)
         self._setup_engine()
+        self.stage_test_set(ds)
 
     # ------------------------------------------------------ roster plumbing
     def _rebuild_structures(self, labels_full) -> None:
@@ -345,7 +346,7 @@ class LoopClusteredKD(_ClusteredKDBase):
 
     def eval(self):
         return evaluate(self.student_steps["eval"], self.global_student,
-                        self.ds.x_test, self.ds.y_test)
+                        self.test_set)
 
     def checkpoint_arrays(self):
         arrs = {"student": self.global_student, "teachers": self.teachers,
@@ -766,7 +767,7 @@ class ShardedClusteredKD(_ClusteredKDBase):
 
     def eval(self):
         return evaluate(self.student_steps["eval"], self.sp_global,
-                        self.ds.x_test, self.ds.y_test)
+                        self.test_set)
 
     def checkpoint_arrays(self):
         arrs = {"student": self.sp_global, "teachers": self.tp_k,

@@ -69,6 +69,7 @@ class FLHC(Algorithm):
             labels, participation=cfg.participation,
             clients_per_round=cfg.clients_per_round,
             dropout_rate=cfg.dropout_rate, seed=cfg.seed)
+        self.stage_test_set(ds)
 
     def run_round(self, plan, rnd):
         cfg, key = self.cfg, self.key
@@ -95,8 +96,7 @@ class FLHC(Algorithm):
         # (full-population cluster sizes, independent of this round's sample)
         accs, losses, ws = [], [], []
         for cm, c in zip(self.cluster_models, self.clusters):
-            a, l = evaluate(self.steps["eval"], cm,
-                            self.ds.x_test, self.ds.y_test)
+            a, l = evaluate(self.steps["eval"], cm, self.test_set)
             w = sum(self.shards[i].num_examples for i in c)
             accs.append(a * w)
             losses.append(l * w)

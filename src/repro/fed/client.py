@@ -4,21 +4,26 @@ teacher/student distillation step.
 """
 from __future__ import annotations
 
-import functools
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro import perf
 from repro.core.distill import distillation_loss, softmax_cross_entropy
 from repro.kernels import ops
 from repro.optim import Optimizer, apply_updates, fedprox_penalty
 
+EVAL_BATCH = 256        # rows per step of the eval program
+
 
 def make_steps(fwd: Callable, opt: Optimizer, *, kd_temperature: float = 2.0,
                kd_alpha: float = 0.5, prox_mu: float = 0.0):
-    """Returns dict of jitted steps: ce / prox / distill / eval."""
+    """Returns dict of jitted steps: ce / prox / distill / eval (the
+    staged test set's sums, ``evaluate``)."""
 
     def ce_loss(params, batch, key):
         logits = fwd(params, batch["x"], train=True, key=key)
@@ -69,34 +74,55 @@ def make_steps(fwd: Callable, opt: Optimizer, *, kd_temperature: float = 2.0,
 
         return distill_step
 
-    @functools.partial(jax.jit, static_argnames=())
-    def eval_batch(params, x, y):
+    @jax.jit
+    def eval_set(params, xs, ys):
+        """Sums over a staged test set (``stage_test_set``): correct
+        predictions, loss and valid rows (label >= 0), one batch of the
+        ``[n_batches, batch, ...]`` view at a time, so memory holds one
+        batch's activations."""
         with jax.named_scope("eval_forward"):
-            logits = fwd(params, x, train=False, key=None)
-            loss = softmax_cross_entropy(logits, y)
-            acc = jnp.mean(
-                (jnp.argmax(logits, -1) == y).astype(jnp.float32))
-        return acc, loss
+            def batch_sums(xy):
+                x, y = xy
+                logits = fwd(params, x, train=False, key=None)
+                valid = y >= 0
+                n = jnp.sum(valid.astype(jnp.int32))
+                hits = jnp.sum(
+                    ((jnp.argmax(logits, -1) == y) & valid).astype(jnp.int32))
+                loss = softmax_cross_entropy(logits, y) * n
+                return hits, loss, n
+
+            hits, loss, n = jax.lax.map(batch_sums, (xs, ys))
+        return jnp.sum(hits), jnp.sum(loss), jnp.sum(n)
 
     return {"ce": ce_step, "prox": prox_step, "make_distill": make_distill_step,
-            "eval": eval_batch}
+            "eval": eval_set}
 
 
-def evaluate(eval_batch, params, x, y, batch_size: int = 256):
-    """Dataset accuracy/loss via batched eval (last partial batch included).
+def stage_test_set(x, y, mesh=None):
+    """The test set on the device, once per strategy: ``x`` padded with
+    zero rows and ``y`` with -1 (padding to the ``eval`` program) to a whole
+    number of batches, as ``[n_batches, EVAL_BATCH, ...]``.  Replicated over
+    ``mesh`` where the strategy has one (the placement its global params
+    carry after a round), else on the default device.  The bytes count as
+    ``perf``'s ``h2d_bytes``."""
+    n_batches = -(-len(y) // EVAL_BATCH)
+    pad = n_batches * EVAL_BATCH - len(y)
+    xs = np.concatenate([x, np.zeros((pad,) + x.shape[1:], x.dtype)])
+    ys = np.concatenate([y, np.full(pad, -1, y.dtype)])
+    xs = xs.reshape((n_batches, EVAL_BATCH) + x.shape[1:])
+    ys = ys.reshape(n_batches, EVAL_BATCH)
+    perf.count_bytes("h2d_bytes", xs, ys)
+    where = None if mesh is None else NamedSharding(mesh, P())
+    return jax.device_put((xs, ys), where)
 
-    Per batch, ``perf`` records ``eval_step`` (slice, implicit transfer of
-    the host batch, dispatch) and ``sync`` (the two blocking reads)."""
-    accs, losses, ns = [], [], []
-    for s in range(0, len(y), batch_size):
-        with perf.span("eval_step"):
-            xa, ya = x[s:s + batch_size], y[s:s + batch_size]
-            perf.count_bytes("h2d_bytes", xa, ya)
-            a, l = eval_batch(params, xa, ya)
-        with perf.span("sync"):
-            perf.count("host_syncs", 2)
-            accs.append(float(a) * len(ya))
-            losses.append(float(l) * len(ya))
-        ns.append(len(ya))
-    n = sum(ns)
-    return sum(accs) / n, sum(losses) / n
+
+def evaluate(eval_set, params, test_set):
+    """(accuracy, loss) of ``params`` over a staged test set: one program
+    and one read.  ``perf`` records ``eval_step`` (the dispatch) and
+    ``sync`` (the read)."""
+    with perf.span("eval_step"):
+        out = eval_set(params, *test_set)
+    with perf.span("sync"):
+        perf.count("host_syncs")
+        hits, loss, n = jax.device_get(out)
+    return int(hits) / int(n), float(loss) / int(n)

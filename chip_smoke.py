@@ -142,11 +142,12 @@ def timed_run(ds, cfg, label):
 
 
 def capture_round_programs():
-    """Record the argument shapes of every packed KD round program the
-    strategy builds, so its lowering can be inspected after the run."""
+    """Record the argument shapes of every KD round program the strategy
+    builds (``teacher_data="leader"``, the default: one teacher per cluster
+    row), so its lowering can be inspected after the run."""
     from repro.fed import sharded
     made = []
-    make = sharded.make_packed_kd_round
+    make = sharded.make_leader_kd_round
 
     def capturing(*args, **kwargs):
         fn = make(*args, **kwargs)
@@ -161,7 +162,7 @@ def capture_round_programs():
             return fn(*a)
         return call
 
-    sharded.make_packed_kd_round = capturing
+    sharded.make_leader_kd_round = capturing
     return made
 
 
@@ -247,10 +248,11 @@ def phase_four_chips(ds, seed):
           flush=True)
     check(staged and spans == {4} and rows == {4},
           "staged client stacks do not span the 4 devices")
-    # the round program's params, optimizer states and batch stacks (the
-    # per-slot step counts and keys are (S,) vectors jit reshards itself)
+    # the round program's student params, optimizer states and batch
+    # stacks (the per-slot step counts and keys are (S,) vectors jit
+    # reshards itself; the (K,) teacher rows are replicated)
     specs = made[0]["specs"]
-    slot_specs = jax.tree_util.tree_leaves(specs[:6] + specs[7:9])
+    slot_specs = jax.tree_util.tree_leaves(specs[2:4] + specs[7:9])
     check(all(len(x.sharding.device_set) == 4
               and x.sharding.shard_shape(x.shape)[0] == 4
               for x in slot_specs),

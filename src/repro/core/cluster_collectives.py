@@ -101,13 +101,13 @@ def fedsikd_global_mean(tree, axis_name: str, groups: list[list[int]],
 def teacher_sync(tree, axis_name: str, groups: list[list[int]]):
     """Intra-cluster teacher-replica sync (Alg. 1 line 12, mesh-mapped).
 
-    In the sharded KD engine every member device of a cluster carries its own
-    copy of the cluster teacher.  After a block of local teacher steps the
-    copies are reconciled to their cluster mean: with ``teacher_data="leader"``
-    all members stepped on identical leader batches, so this is a numerical
-    no-op that only pins replicas together; with ``teacher_data="cluster"``
-    members stepped on their OWN shards and the mean implements data-parallel
-    teacher training over the union of cluster data (DESIGN.md §7).
+    With ``teacher_data="cluster"`` every member device of a cluster carries
+    its own copy of the cluster teacher and steps it on its OWN shard; after
+    a block of local teacher steps the copies are reconciled to their
+    cluster mean, which implements data-parallel teacher training over the
+    union of cluster data (DESIGN.md §7).  ``teacher_data="leader"`` needs
+    no sync: the packed engine trains each cluster's teacher once, as a row
+    of the canonical stacks (``fed/sharded.make_leader_kd_round``).
 
     Integer leaves (e.g. the Adam step count) are kept per-device rather
     than averaged: a float mean truncated back to int corrupts the count —

@@ -372,18 +372,29 @@ class ShardedClusteredKD(_ClusteredKDBase):
     jitted program per round; fed/sharded.py owns the collective programs).
 
     Canonical state lives per CLUSTER between rounds (teachers: a (K, ...)
-    stacked pytree; student: one global pytree): each round the strategy
-    gathers it onto the plan's slots, runs the collective program, and
-    scatters the refreshed teachers back from each cluster's first active
-    slot.  Clusters with no sampled member keep their teacher untouched —
-    exactly like the loop engine skipping them (DESIGN.md §8).
+    stacked pytree; student: one global pytree).  How a round refreshes the
+    teachers follows ``teacher_data`` (DESIGN.md §7):
+
+    - ``"leader"``: every member of a cluster would train the same teacher
+      on the same leader batches with the same key, so the round program
+      refreshes each cluster's teacher ONCE, as a row of the (K, ...)
+      stacks, on the leader feed staged on the device
+      (``sh.make_leader_kd_round``); each lane reads its cluster's row.
+    - ``"cluster"``: data-parallel teacher training over the members'
+      own shards — the strategy gathers a teacher replica onto every
+      participating slot, the program syncs the replicas
+      (``sh.make_packed_kd_round``), and the strategy scatters each
+      refreshed teacher back from its cluster's first active slot.
+
+    Clusters with no sampled member keep their teacher untouched — exactly
+    like the loop engine skipping them (DESIGN.md §8).
 
     Lifecycle events re-scatter slot state for free — slot state is derived
     per round from the canonical (K, ...) stacks — so ``_post_lifecycle``
-    only has to re-stage the teacher feed (leaders may have changed) and
-    refresh the slot stager.  The mesh itself is sized for the client
-    UNIVERSE at setup (``Algorithm.forced_devices``), so the compiled round
-    program survives every join."""
+    only has to re-stage the leader feed (leaders may have changed).  The
+    mesh itself is sized for the client UNIVERSE at setup
+    (``Algorithm.forced_devices``), so the compiled round program survives
+    every join."""
 
     engine = "sharded"
 
@@ -398,6 +409,7 @@ class ShardedClusteredKD(_ClusteredKDBase):
                 "teacher_data='cluster' needs the whole cluster on the mesh "
                 "at once; wave-scheduled rounds require "
                 "teacher_data='leader'")
+        self.leader_mode = cfg.teacher_data == "leader"
         # the mesh hosts ONE wave; the cohort streams through it
         self.mesh = make_fed_client_mesh(scheduler.wave_slots,
                                          pack=cfg.pack,
@@ -422,7 +434,8 @@ class ShardedClusteredKD(_ClusteredKDBase):
         # counts) and the one-off (R, steps, B, ...) staging of the BASE
         # data pool — virtual clients stage through the store's row map at
         # gather time, so host memory scales with the pool, never the
-        # universe (DESIGN.md §15)
+        # universe (DESIGN.md §15).  Every slot streams its own shard: the
+        # students' feed, and in "cluster" mode the teacher replicas' too.
         store = self.store
         self._base_counts = sh.client_step_counts(store.base, cfg.batch_size,
                                                   cfg.local_epochs)
@@ -430,33 +443,44 @@ class ShardedClusteredKD(_ClusteredKDBase):
         self.sx_all, self.sy_all = sh.stack_client_data(
             store.base, int(self._base_counts.max()), cfg.batch_size,
             seed=cfg.seed)
-        # teacher-feed staging width: with a lifecycle on, pad to the
-        # universe-max step budget so a leader change never changes the
-        # compiled scan length (static runs keep today's exact-max width)
-        self._t_cap = (int(self.s_steps_all.max())
-                       if self.lifecycle is not None else None)
+        self.stager = sh.WaveStager(
+            self.mesh, self.sx_all, self.sy_all,
+            row_maps=(store.row_of, store.row_of),
+            capacity=scheduler.n_waves + 1)
+        self._feed_rows = None
         self._restage_teacher_feed()
 
-        self.round_fn = sh.make_packed_kd_round(
-            self.mesh, cfg.pack, t_fwd, s_fwd, self.opt, self.s_opt,
-            kd_temperature=cfg.kd_temperature, kd_alpha=cfg.kd_alpha,
-            kd_impl=cfg.kd_impl, donate=cfg.donate)
+        kd = dict(kd_temperature=cfg.kd_temperature, kd_alpha=cfg.kd_alpha,
+                  kd_impl=cfg.kd_impl, donate=cfg.donate)
+        if self.leader_mode:
+            self.round_fn = sh.make_leader_kd_round(
+                self.mesh, cfg.pack, t_fwd, s_fwd, self.opt, self.s_opt,
+                **kd)
+        else:
+            self.round_fn = sh.make_packed_kd_round(
+                self.mesh, cfg.pack, t_fwd, s_fwd, self.opt, self.s_opt,
+                **kd)
         self._build_prep_finish()
 
     def _build_prep_finish(self):
-        """The pre-round GATHER and post-round SCATTER as two jitted
-        programs.  Eagerly, these are hundreds of per-leaf dispatches on
-        sharded arrays (~30ms each — the profiled hot spot: the scatter
-        alone cost ~19s/round); jitted they are two fixed-shape programs
-        whose index operands (``kidx``, ``refreshed``, ``safe``) are traced
-        inputs, so sampled rounds never recompile.
+        """The pre-round GATHER and post-round MERGE as jitted programs.
+        Eagerly, these are hundreds of per-leaf dispatches on sharded arrays
+        (~30ms each — the profiled hot spot: the scatter alone cost
+        ~19s/round); jitted they are fixed-shape programs whose index
+        operands are traced inputs, so sampled rounds never recompile.
 
         ``prep`` emits every (S, ...) output with the packed slot sharding,
         which is what makes the round program's donation usable: the round
-        consumes prep's outputs in place.  ``finish`` donates the round's
-        slot outputs (tp_s/ts_s/sp_s) but NEVER the canonical (K, ...)
-        stacks — the async checkpoint writer may still hold references to
-        those from a previous round's submit (DESIGN.md §13)."""
+        consumes prep's outputs in place.  It broadcasts the global student
+        to every slot with a fresh optimizer state; in "cluster" mode it
+        also gathers each slot's teacher replica from the (K, ...) stacks.
+        ``finish`` merges the refreshed teachers into the (K, ...)
+        accumulators (leader mode: the program's (K,) rows where their
+        budget was non-zero; cluster mode: scattered from each cluster's
+        first active slot) and extracts the aggregated student.  It donates
+        the round's outputs but NEVER the canonical (K, ...) stacks — the
+        async checkpoint writer may still hold references to those from a
+        previous round's submit (DESIGN.md §13)."""
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
         cfg, sh = self.cfg, self.sh
@@ -464,22 +488,41 @@ class ShardedClusteredKD(_ClusteredKDBase):
         s_opt = self.s_opt
         tree_map = jax.tree_util.tree_map
         slot_sh = NamedSharding(self.mesh, P(sh.AXIS))
+        donate = (0, 1, 2) if cfg.donate else ()
+
+        def students(sp_global):
+            sp_s = tree_map(
+                lambda a: jnp.broadcast_to(a, (S,) + a.shape), sp_global)
+            ss_s = jax.vmap(s_opt.init)(sp_s)   # fresh student opt (loop too)
+            return sp_s, ss_s
+
+        def merge(new, old, refreshed):
+            def upd(n, o):
+                return jnp.where(
+                    refreshed.reshape((K,) + (1,) * (o.ndim - 1)), n, o)
+            return tree_map(upd, new, old)
+
+        if self.leader_mode:
+            self._prep = jax.jit(students, out_shardings=slot_sh)
+
+            def finish(tp_w, ts_w, sp_s, tp_k, ts_k, t_n):
+                refreshed = t_n > 0
+                return (merge(tp_w, tp_k, refreshed),
+                        merge(ts_w, ts_k, refreshed),
+                        tree_map(lambda a: a[0], sp_s))
+
+            self._finish = jax.jit(finish, donate_argnums=donate)
+            return
 
         def prep(tp_k, ts_k, sp_global, kidx):
             tp_s = tree_map(lambda a: a[kidx], tp_k)
             ts_s = tree_map(lambda a: a[kidx], ts_k)
-            sp_s = tree_map(
-                lambda a: jnp.broadcast_to(a, (S,) + a.shape), sp_global)
-            ss_s = jax.vmap(s_opt.init)(sp_s)   # fresh student opt (loop too)
-            return tp_s, ts_s, sp_s, ss_s
+            return (tp_s, ts_s) + students(sp_global)
 
         self._prep = jax.jit(prep, out_shardings=slot_sh)
 
         def scatter(new, old, refreshed, safe):
-            def upd(n, o):
-                mask = refreshed.reshape((K,) + (1,) * (o.ndim - 1))
-                return jnp.where(mask, n[safe], o)
-            return tree_map(upd, new, old)
+            return merge(tree_map(lambda n: n[safe], new), old, refreshed)
 
         def finish(tp_s, ts_s, sp_s, tp_k, ts_k, refreshed, safe):
             tp_k = scatter(tp_s, tp_k, refreshed, safe)
@@ -487,7 +530,6 @@ class ShardedClusteredKD(_ClusteredKDBase):
             sp0 = tree_map(lambda a: a[0], sp_s)
             return tp_k, ts_k, sp0
 
-        donate = (0, 1, 2) if cfg.donate else ()
         self._finish = jax.jit(finish, donate_argnums=donate)
 
         def finish_warm(tp_s, ts_s, tp_k, ts_k, refreshed, safe):
@@ -498,43 +540,30 @@ class ShardedClusteredKD(_ClusteredKDBase):
         self._finish_warm = jax.jit(finish_warm, donate_argnums=donate_w)
 
     def _restage_teacher_feed(self):
-        """(Re)build the per-client teacher source, its step budgets, and
-        the slot stager — at setup and after every roster rebuild.  Skipped
-        when the feed is unchanged: "cluster" mode always streams each
-        client's own shard, and in "leader" mode a re-clustering that keeps
-        every client's leader (the common drift case) changes nothing —
-        re-staging is O(total dataset) host work + a full device transfer."""
-        cfg, sh, store = self.cfg, self.sh, self.store
-        total = len(store)
-        # per-client teacher feed (DESIGN.md §7): "leader" streams the
-        # cluster leader's shard to every slot (identical batches ->
-        # replicas stay in sync between collectives); "cluster" streams each
-        # client's OWN shard, which teacher_sync turns into data-parallel
-        # training over the union.  Off-roster clients keep their own shard
-        # (their rows are only ever staged on idle slots, which never train).
-        if cfg.teacher_data == "leader":
-            cidx = self.scheduler.cluster_idx
-            leaders = np.asarray(self.leaders, np.int64)
-            feed_of = np.where(cidx >= 0, leaders[np.maximum(cidx, 0)],
-                               np.arange(total))
-        else:
-            feed_of = np.arange(total)
-        if getattr(self, "_feed_of", None) is not None \
-                and np.array_equal(feed_of, self._feed_of):
+        """Leader mode: per teacher row, the compact cluster index that
+        hosts it (its keys fold it), its leader's step budget, and the
+        leader's (steps, B, ...) feed staged on the device once, replicated
+        over the mesh as (K, ...) rows and counted under ``h2d_bytes`` — at
+        setup and after a roster rebuild that changes a leader (a
+        re-clustering that keeps every leader, the common drift case,
+        stages nothing).  A row no cluster holds carries row 0's feed with
+        a zero budget: it never trains.  Cluster mode streams each slot's
+        own shard, which the stager already holds."""
+        if not self.leader_mode:
             return
-        self._feed_of = feed_of
-        # the teacher stack holds the BASE pool rows once; each slot maps to
-        # its feed's base row at gather time (the old per-client t_src stack
-        # duplicated every leader's data C times over)
-        self._t_map = store.row_of[feed_of]
-        self.t_steps_all = self._base_counts[self._t_map]
-        cap = self._t_cap or int(self.t_steps_all.max())
-        self.tx_all, self.ty_all = sh.stack_client_data(
-            store.base, cap, cfg.batch_size, seed=cfg.seed)
-        self.stager = sh.WaveStager(
-            self.mesh, self.tx_all, self.ty_all, self.sx_all, self.sy_all,
-            row_maps=(self._t_map, self._t_map, store.row_of, store.row_of),
-            capacity=self.scheduler.n_waves + 1)
+        K, store, hosted = self.K, self.store, self.cluster_ids
+        self._row_cluster = np.zeros(K, np.int64)
+        self._row_cluster[hosted] = np.arange(len(hosted))
+        rows = np.zeros(K, np.int64)
+        rows[hosted] = store.row_of[np.asarray(self.leaders, np.int64)]
+        self._leader_steps = np.zeros(K, np.int32)
+        self._leader_steps[hosted] = self._base_counts[rows[hosted]]
+        if self._feed_rows is not None \
+                and np.array_equal(rows, self._feed_rows):
+            return
+        self._feed_rows = rows
+        self.tx_k, self.ty_k = self.sh.replicate(
+            self.mesh, self.sx_all[rows], self.sy_all[rows])
 
     def _post_lifecycle(self):
         self._restage_teacher_feed()
@@ -553,11 +582,20 @@ class ShardedClusteredKD(_ClusteredKDBase):
         comp = np.where(plan.active, plan.slot_cluster, 0)
         return np.where(plan.active, self.cluster_ids[comp], 0)
 
+    def _row_steps(self, plan):
+        """(K,) leader-mode teacher budgets: each row's leader budget where
+        ``plan`` holds an active slot of its cluster, else 0 (that teacher
+        stays frozen)."""
+        t_n = np.zeros(self.K, np.int32)
+        rows = self._teacher_row(plan)[plan.active]
+        t_n[rows] = self._leader_steps[rows]
+        return t_n
+
     def _scatter_src(self, plan):
-        """Host-side scatter operands for ``_finish``: which teacher rows a
-        round refreshed (``refreshed``, (K,) bool) and the first active slot
-        sourcing each (``safe``, (K,) int; untouched rows read slot 0 but
-        are masked out).  Traced inputs to the jitted scatter — index
+        """Cluster-mode scatter operands for ``_finish``: which teacher rows
+        a round refreshed (``refreshed``, (K,) bool) and the first active
+        slot sourcing each (``safe``, (K,) int; untouched rows read slot 0
+        but are masked out).  Traced inputs to the jitted scatter — index
         changes never recompile."""
         K, S = self.K, self.S
         row = self._teacher_row(plan)
@@ -578,62 +616,70 @@ class ShardedClusteredKD(_ClusteredKDBase):
                                         plan)
 
     def _teacher_keys(self, salt, plan):
-        """Teacher-step keys.  Leader mode: slots of a cluster share one key
-        (sh.slot_cluster_keys — replicas stepping on identical leader
-        batches stay bitwise in sync between sync collectives).  Cluster
-        mode: per-client keys, offset 10_000 to stay disjoint from the
-        student stream (each slot steps on its own client's shard anyway)."""
+        """Teacher-step keys.  Leader mode: one per teacher row, folded by
+        the compact cluster index hosting it (sh.cluster_keys), whichever
+        slots the cluster's members hold.  Cluster mode: per-client keys,
+        offset 10_000 to stay disjoint from the student stream (each slot
+        steps on its own client's shard anyway)."""
         base = jax.random.fold_in(self.key,
                                   self.sh.to_device(np.uint32(salt)))
-        if self.cfg.teacher_data == "leader":
-            return self.sh.slot_cluster_keys(base, plan)
+        if self.leader_mode:
+            return self.sh.cluster_keys(base, self._row_cluster)
         return self.sh.slot_client_keys(base, plan, offset=10_000)
 
     # ------------------------------------------------------------- lifecycle
     def warmup(self):
         """Alg. 1 KD-establishment: teacher warm-up before round 1 as a
-        separate jitted collective program (a checkpoint's teacher state
-        already includes it, so the driver skips this on resume)."""
+        separate jitted program (a checkpoint's teacher state already
+        includes it, so the driver skips this on resume)."""
         cfg, sh = self.cfg, self.sh
         if cfg.teacher_warmup_epochs <= 0:
             return
-        w_steps_all = ((self.t_steps_all // max(cfg.local_epochs, 1))
-                       * cfg.teacher_warmup_epochs).astype(np.int32)
-        wx_all, wy_all = sh.stack_client_data(
-            self.store.base, int(w_steps_all.max()), cfg.batch_size,
-            seed=cfg.seed)
         planw = self.scheduler.warmup_plan()
-        warm = sh.make_packed_teacher_phase(self.mesh, cfg.pack,
-                                            self.t_model[1], self.opt,
-                                            donate=cfg.donate)
-        # Wave execution (DESIGN.md §15): every wave preps from the SAME
-        # round-start snapshot; in leader mode each wave's refresh of a
-        # cluster is bitwise-reproducible from that snapshot, so repeated
-        # scatters agree and the last wave's write stands.
-        tp0, ts0 = self.tp_k, self.ts_k
-        tp_acc, ts_acc = self.tp_k, self.ts_k
-        wloss = 0.0
-        for w in range(planw.n_waves):
-            wp = planw.wave(w)
-            if not wp.active.any():
-                continue
+
+        def warm_of(steps):             # round budgets -> warm-up budgets
+            return ((steps // max(cfg.local_epochs, 1))
+                    * cfg.teacher_warmup_epochs).astype(np.int32)
+
+        if self.leader_mode:
+            # each wave of the warm plan would refresh its clusters from
+            # the same snapshot with the same feed and keys, so one pass
+            # over the rows the plan holds gives the same teachers
+            w_n = warm_of(self._row_steps(planw))
+            wx, wy = sh.stack_client_data(
+                [self.store.base[int(r)] for r in self._feed_rows],
+                max(int(w_n.max()), 1), cfg.batch_size, seed=cfg.seed)
+            phase = sh.make_leader_teacher_phase(self.mesh, self.t_model[1],
+                                                 self.opt)
+            self.tp_k, self.ts_k, wl = phase(
+                self.tp_k, self.ts_k, *sh.replicate(self.mesh, wx, wy),
+                sh.to_device(w_n), self._teacher_keys(9001, planw))
+        else:
+            # one wave: cluster mode needs the whole cluster on the mesh
+            wp = planw.wave(0)
+            w_steps_all = warm_of(self.s_steps_all)
+            wx_all, wy_all = sh.stack_client_data(
+                self.store.base, int(w_steps_all.max()), cfg.batch_size,
+                seed=cfg.seed)
+            warm = sh.make_packed_teacher_phase(self.mesh, cfg.pack,
+                                                self.t_model[1], self.opt,
+                                                donate=cfg.donate)
             # prep's slot-sharded gather (sp/ss ride along unused) keeps the
             # warm program's donation usable, exactly as in run_round
             tp_s, ts_s, _sp, _ss = self._prep(
-                tp0, ts0, self.sp_global,
+                self.tp_k, self.ts_k, self.sp_global,
                 jnp.asarray(self._teacher_row(wp)))
+            row_of = self.store.row_of
             wx, wy = sh.stage_on_slots(self.mesh, wp, wx_all, wy_all,
-                                       row_maps=(self._t_map, self._t_map))
+                                       row_maps=(row_of, row_of))
             tp_s, ts_s, wl = warm(
                 tp_s, ts_s, wx, wy, jnp.asarray(wp.steps_for(w_steps_all)),
                 self._teacher_keys(9001, wp), jnp.asarray(wp.sync_matrix()))
             refreshed, safe = self._scatter_src(wp)
-            tp_acc, ts_acc = self._finish_warm(
-                tp_s, ts_s, tp_acc, ts_acc, refreshed, safe)
-            wloss = float(wl)
-        self.tp_k, self.ts_k = tp_acc, ts_acc
+            self.tp_k, self.ts_k = self._finish_warm(
+                tp_s, ts_s, self.tp_k, self.ts_k, refreshed, safe)
         if self.progress:
-            print(f"  warmup  teacher_loss={wloss:.4f}")
+            print(f"  warmup  teacher_loss={float(wl):.4f}")
 
     def prefetch(self, plan):
         """Overlap the NEXT round's slot staging with the current round's
@@ -679,11 +725,12 @@ class ShardedClusteredKD(_ClusteredKDBase):
             # the program still trains the stragglers (buffered below), but
             # its aggregate is discarded and the global student holds
             row, scales = np.zeros(plan.n_slots, np.float32), []
-        # Wave loop (DESIGN.md §15): every wave preps from the round-start
-        # snapshots, streams through the ONE compiled program, and folds
-        # into host-side accumulators.  Teachers: leader-mode waves refresh
-        # a cluster bitwise-reproducibly from the snapshot, so repeated
-        # scatters agree.  Student: per-wave partial sums, folded below.
+        # Wave loop (DESIGN.md §15): every wave refreshes the teachers of
+        # the clusters it holds from the round-start snapshots, streams
+        # through the ONE compiled program, and folds into host-side
+        # accumulators.  Teachers: leader-mode waves refresh a cluster
+        # bitwise-reproducibly from the snapshot, so repeated merges agree.
+        # Student: per-wave partial sums, folded below.
         tp0, ts0, sp_start = self.tp_k, self.ts_k, self.sp_global
         tp_acc, ts_acc = self.tp_k, self.ts_k
         partials, losses = [], []
@@ -695,28 +742,44 @@ class ShardedClusteredKD(_ClusteredKDBase):
                 continue
             with perf.span("stage"):
                 with perf.span("stager"):
-                    tx, ty, sx, sy = self.stager.stage(wp)
+                    sx, sy = self.stager.stage(wp)
                 with perf.span("prep"):
-                    tp_s, ts_s, sp_s, ss_s = self._prep(
-                        tp0, ts0, sp_start,
-                        sh.to_device(self._teacher_row(wp)))
+                    if self.leader_mode:
+                        tp_s, ts_s = tp0, ts0
+                        sp_s, ss_s = self._prep(sp_start)
+                    else:
+                        tp_s, ts_s, sp_s, ss_s = self._prep(
+                            tp0, ts0, sp_start,
+                            sh.to_device(self._teacher_row(wp)))
             with perf.span("compute"):
                 with perf.span("dispatch"):
+                    # the teacher operands: leader mode the (K,) rows'
+                    # staged leader feed and budgets, and each slot's row
+                    # (-1 idle); cluster mode each slot's own feed and
+                    # budget, and the sync operator
+                    s_n = wp.steps_for(self.s_steps_all)
+                    if self.leader_mode:
+                        t_row = self._teacher_row(wp)
+                        tx, ty, t_n = self.tx_k, self.ty_k, self._row_steps(wp)
+                        route = np.where(wp.active, t_row, -1).astype(np.int32)
+                        t_lanes = wp.active & (t_n[t_row] > 0)
+                    else:
+                        tx, ty, t_n, route = sx, sy, s_n, wp.sync_matrix()
+                        t_lanes = t_n > 0
+                    perf.count("teacher_lanes", len(t_n))
+                    t_n = sh.to_device(t_n)
                     # disjoint even/odd salts keep teacher and student PRNG
                     # streams from colliding on clients whose id equals
                     # their cluster index (sh.to_device: explicit transfers,
                     # legal under the guards); keys fold client/cluster
                     # ids, so a client's stream is invariant to its wave
                     # placement
-                    t_n = wp.steps_for(self.t_steps_all)
-                    s_n = wp.steps_for(self.s_steps_all)
                     (tp_s, ts_s, sp_s, sp_local, _ss_s, t_loss,
                      s_loss) = self.round_fn(
-                        tp_s, ts_s, sp_s, ss_s, tx, ty,
-                        sh.to_device(t_n), sx, sy, sh.to_device(s_n),
-                        self._teacher_keys(2 * rnd, wp),
+                        tp_s, ts_s, sp_s, ss_s, tx, ty, t_n, sx, sy,
+                        sh.to_device(s_n), self._teacher_keys(2 * rnd, wp),
                         self._student_keys(2 * rnd + 1, wp),
-                        sh.to_device(wp.sync_matrix()),
+                        sh.to_device(route),
                         sh.to_device(np.ascontiguousarray(
                             row[w * ws:(w + 1) * ws])))
                 if w + 1 < n_waves:
@@ -727,12 +790,15 @@ class ShardedClusteredKD(_ClusteredKDBase):
                 # wave w+1 is not dispatched before then
                 with perf.span("sync"):
                     perf.count("host_syncs", 2)
-                    losses.append((float(t_loss), (t_n > 0).sum(),
+                    losses.append((float(t_loss), t_lanes.sum(),
                                    float(s_loss), (s_n > 0).sum()))
             with perf.span("aggregate"):
-                refreshed, safe = self._scatter_src(wp)
+                # leader mode merges the rows whose budget was non-zero;
+                # cluster mode scatters from each cluster's first slot
+                merge_ops = ((t_n,) if self.leader_mode
+                             else self._scatter_src(wp))
                 tp_acc, ts_acc, sp0_w = self._finish(
-                    tp_s, ts_s, sp_s, tp_acc, ts_acc, refreshed, safe)
+                    tp_s, ts_s, sp_s, tp_acc, ts_acc, *merge_ops)
                 partials.append(sp0_w)
             if has_async:
                 # straggler lanes: pre-aggregation students into the

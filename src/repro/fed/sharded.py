@@ -13,15 +13,21 @@ rounds (DESIGN.md §3, §8).
 
 One mesh entry point per algorithm family:
 
-- ``make_packed_kd_round``       — the full FedSiKD round (Alg. 1) on the
-  packed mesh: per-cluster TEACHER REPLICAS on every participating slot,
-  teacher CE steps, intra-cluster teacher sync
-  (``cluster_collectives.packed_teacher_sync``), student DISTILLATION steps
-  that call the fused Pallas ``kd_distillation_loss`` kernel inside the
-  ``jax.lax.scan`` step loop, and the grouped student aggregation — all
-  masked per slot by the plan's step budgets (idle slots freeze).
-  ``make_packed_teacher_phase`` is Alg. 1's pre-round KD-establishment
-  (teacher warm-up) as a separate jitted collective program.
+- ``make_leader_kd_round``       — the full FedSiKD round (Alg. 1) with
+  ``teacher_data="leader"``: each cluster's teacher refreshed ONCE, on its
+  leader's batches (a ``vmap`` over the canonical ``(K, ...)`` teacher
+  rows), then student DISTILLATION steps against each lane's cluster
+  teacher that call the fused Pallas ``kd_distillation_loss`` kernel inside
+  the ``jax.lax.scan`` step loop, and the grouped student aggregation — all
+  masked by the plan's step budgets (idle slots and absent clusters
+  freeze).  ``make_leader_teacher_phase`` is its pre-round
+  KD-establishment (teacher warm-up).
+- ``make_packed_kd_round``       — the same round with
+  ``teacher_data="cluster"``: per-cluster TEACHER REPLICAS on every
+  participating slot, each stepping on its own member's shard, reconciled
+  by the intra-cluster teacher sync (``cluster_collectives.
+  packed_teacher_sync``) — data-parallel teacher training.
+  ``make_packed_teacher_phase`` is its warm-up.
 - ``make_packed_baseline_round`` — FedAvg / FedProx: plain-CE (or proximal
   CE against the broadcast round-start global params) local steps, then ONE
   all-clients example-weighted grouped mean (no cluster structure — a
@@ -56,6 +62,7 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro import guards, perf
@@ -112,6 +119,14 @@ def to_device(x):
     the guards), its bytes counted as ``perf``'s ``h2d_bytes``."""
     perf.count_bytes("h2d_bytes", x)
     return jax.device_put(x)
+
+
+def replicate(mesh, *arrays):
+    """``jax.device_put`` of host arrays replicated over ``mesh`` (every
+    device holds each whole array), their bytes counted as ``perf``'s
+    ``h2d_bytes``."""
+    perf.count_bytes("h2d_bytes", *arrays)
+    return jax.device_put(arrays, NamedSharding(mesh, P()))
 
 
 def stage_on_slots(mesh, plan: RoundPlan, *arrays, row_maps=None,
@@ -330,12 +345,12 @@ def slot_client_keys(base, plan: RoundPlan, *, offset: int = 0):
         (offset + cid.astype(np.int64)).astype(np.uint32)))
 
 
-def slot_cluster_keys(base, plan: RoundPlan):
-    """One PRNG key per slot, folded by the slot's CLUSTER index: all slots
-    of a cluster share one key (identical batches + identical dropout masks
-    keep teacher replicas bitwise in sync between sync collectives)."""
-    kidx = np.where(plan.active, plan.slot_cluster, 0)
-    return _fold_keys(base, to_device(kidx.astype(np.uint32)))
+def cluster_keys(base, cluster_idx):
+    """One PRNG key per teacher row, folded by the compact CLUSTER index
+    hosting it (``RoundPlan.slot_cluster``'s id space): a cluster's teacher
+    steps on the same key whichever slots its members hold."""
+    return _fold_keys(base, to_device(
+        np.asarray(cluster_idx).astype(np.uint32)))
 
 
 @functools.partial(jax.jit, static_argnums=1)
@@ -406,27 +421,83 @@ def _active_mean(loss, n_steps, axis_name):
 
 
 # ----------------------------------------- FedSiKD packed KD round engine
+def _teacher_scan(t_fwd: Callable, t_opt: Optimizer, tp, ts, xs, ys, n_steps,
+                  rng):
+    """The masked teacher CE scan, ``vmap``-ed over the leading axis of its
+    operands: one lane per slot replica (``teacher_data="cluster"``) or one
+    per cluster row (``"leader"``).  Returns ``((tp, ts), loss)``."""
+
+    def lane(tp, ts, xs, ys, n, rng):
+        step = _make_teacher_step(t_fwd, t_opt, rng)
+        return _masked_scan_steps(step, (tp, ts), xs, ys, n)
+
+    with jax.named_scope("teacher_phase"):
+        return jax.vmap(lane)(tp, ts, xs, ys, n_steps, rng)
+
+
+def _make_student_kd(t_fwd: Callable, s_fwd: Callable, s_opt: Optimizer,
+                     pack: int, kd_temperature: float, kd_alpha: float,
+                     kd_impl: str):
+    """The student half of the KD round, shared by both teacher layouts:
+    distillation steps on every lane against that lane's teacher ``tp``
+    (Alg. 1 lines 13-14), then the grouped plan-weighted aggregation (lines
+    16-18).  Returns ``student(sp, ss, sx, sy, s_n, s_rng, tp, agg_row) ->
+    (sp, sp_local, ss, s_loss)`` with per-lane losses."""
+    if kd_impl not in ("fused", "reference"):
+        raise ValueError(
+            f"kd_impl must be 'fused' or 'reference', got {kd_impl!r}")
+
+    def s_lane(sp, ss, xs, ys, n, rng, tp):
+        def s_step(carry, batch):
+            p, s = carry
+            x, y, i = batch
+            k = jax.random.fold_in(rng, i)
+            t_logits = t_fwd(tp, x, train=False, key=None)
+
+            def loss_fn(p):
+                s_logits = s_fwd(p, x, train=True, key=k)
+                if kd_impl == "fused":
+                    return ops.kd_distillation_loss_batched(
+                        s_logits, t_logits, y,
+                        tau=kd_temperature, alpha=kd_alpha)
+                return distillation_loss(s_logits, t_logits, y,
+                                         temperature=kd_temperature,
+                                         alpha=kd_alpha)[0]
+
+            loss, g = jax.value_and_grad(loss_fn)(p)
+            u, s = s_opt.update(g, s, p)
+            return (apply_updates(p, u), s), loss
+
+        return _masked_scan_steps(s_step, (sp, ss), xs, ys, n)
+
+    def student(sp, ss, sx, sy, s_n, s_rng, tp, agg_row):
+        with jax.named_scope("student_kd"):
+            (sp, ss), s_loss = jax.vmap(s_lane)(sp, ss, sx, sy, s_n, s_rng,
+                                                tp)
+        # the pre-aggregation per-slot students ride along so straggler
+        # lanes can be buffered host-side without a second program
+        sp_local = sp
+        with jax.named_scope("cross_lane"):
+            sp = cc.packed_weighted_mean(sp, AXIS, agg_row, pack=pack)
+        return sp, sp_local, ss, s_loss
+
+    return student
+
+
 def make_packed_teacher_phase(mesh, pack: int, t_fwd: Callable,
                               t_opt: Optimizer, *, donate: bool = True):
-    """Jitted teacher-only collective program on the packed mesh: CE steps
-    on every slot's teacher feed (vmap over the ``pack`` lane axis), then
-    intra-cluster teacher sync with the plan's runtime (S, S) operator.
-    Used for Alg. 1's KD-establishment warm-up AND for the per-round teacher
-    refresh.
+    """``teacher_data="cluster"``'s warm-up (Alg. 1's KD establishment) as
+    a jitted collective program on the packed mesh: CE steps on every
+    slot's own shard (vmap over the ``pack`` lane axis), then the
+    intra-cluster teacher sync with the plan's runtime (S, S) operator —
+    data-parallel training of each cluster's teacher over its members.
 
     ``rng`` is one PRNG key per slot (training mode is on, so dropout models
-    get a fresh per-step key, as in the loop engine).  With
-    ``teacher_data="leader"`` the driver hands all slots of a cluster the
-    SAME key, keeping teacher replicas bitwise in sync (see
-    ``algorithms.clustered_kd.ShardedClusteredKD``)."""
+    get a fresh per-step key, as in the loop engine)."""
 
     def phase(tp, ts, xs, ys, n_steps, rng, sync_mat):
-        def lane(tp, ts, xs, ys, n, rng):
-            step = _make_teacher_step(t_fwd, t_opt, rng)
-            return _masked_scan_steps(step, (tp, ts), xs, ys, n)
-
-        with jax.named_scope("teacher_phase"):
-            (tp, ts), loss = jax.vmap(lane)(tp, ts, xs, ys, n_steps, rng)
+        (tp, ts), loss = _teacher_scan(t_fwd, t_opt, tp, ts, xs, ys, n_steps,
+                                       rng)
         with jax.named_scope("cross_lane"):
             tp = cc.packed_teacher_sync(tp, AXIS, sync_mat, pack=pack)
             ts = cc.packed_teacher_sync(ts, AXIS, sync_mat, pack=pack)
@@ -439,16 +510,42 @@ def make_packed_teacher_phase(mesh, pack: int, t_fwd: Callable,
     ), donate_argnums=(0, 1) if donate else ())
 
 
+def make_leader_teacher_phase(mesh, t_fwd: Callable, t_opt: Optimizer):
+    """``teacher_data="leader"``'s warm-up: the teacher CE scan once per
+    cluster ROW (``vmap`` over the canonical ``(K, ...)`` stacks) on each
+    leader's feed, replicated over the mesh.  Rows with a zero step budget
+    come back unchanged.
+
+    Returns phase(tp, ts, xs, ys, n_steps, rng) -> (tp, ts, loss): ``xs``/
+    ``ys`` are the (K, steps, B, ...) leader feed, ``n_steps`` and ``rng``
+    one budget and one key per row, ``loss`` the mean over the rows that
+    trained.  Nothing is donated: the inputs are the canonical stacks."""
+
+    def phase(tp, ts, xs, ys, n_steps, rng):
+        (tp, ts), loss = _teacher_scan(t_fwd, t_opt, tp, ts, xs, ys, n_steps,
+                                       rng)
+        live = n_steps > 0
+        return tp, ts, (jnp.sum(jnp.where(live, loss, 0.0))
+                        / jnp.maximum(jnp.sum(live), 1))
+
+    return jax.jit(jax.shard_map(
+        phase, mesh=mesh, in_specs=(P(),) * 6, out_specs=(P(),) * 3,
+        check_vma=False))
+
+
 def make_packed_kd_round(mesh, pack: int, t_fwd: Callable, s_fwd: Callable,
                          t_opt: Optimizer, s_opt: Optimizer, *,
                          kd_temperature: float = 2.0, kd_alpha: float = 0.5,
                          kd_impl: str = "fused", donate: bool = True):
-    """The full FedSiKD round (Alg. 1 lines 10-18) as ONE jitted collective
-    program over the packed client mesh:
+    """The FedSiKD round (Alg. 1 lines 10-18) for ``teacher_data=
+    "cluster"`` as ONE jitted collective program over the packed client
+    mesh:
 
-      1. teacher CE steps on each slot's teacher feed             (line 12)
+      1. teacher CE steps on every slot's replica of its cluster's teacher,
+         each on the slot's own shard                           (line 12)
       2. intra-cluster teacher sync (grouped all-reduce over
-         (device, lane) slots, runtime operator)
+         (device, lane) slots, runtime operator): data-parallel training
+         of each teacher over its members
       3. student distillation steps vs the synced teacher — the loss is the
          fused Pallas ``kd_distillation_loss`` kernel (``kd_impl="fused"``)
          or the pure-jnp reference (``kd_impl="reference"``)    (line 13-14)
@@ -462,14 +559,10 @@ def make_packed_kd_round(mesh, pack: int, t_fwd: Callable, s_fwd: Callable,
     its local steps but BEFORE aggregation — the semi-async path pulls
     straggler lanes from it into the host-side staleness buffer while the
     program itself stays fixed-shape (stale lanes are merely zero-weighted
-    in ``agg_row``, never recompiled; DESIGN.md §12).  ``sync_mat`` (S, S) and ``agg_row`` (S,) come from the round's
-    ``RoundPlan`` — they are traced inputs, so sampled participation never
-    recompiles.  ``t_rng`` / ``s_rng`` are one PRNG key per slot; they are
-    separate inputs because their sharing patterns differ: student keys are
-    always per-client, while with ``teacher_data="leader"`` the strategy
-    hands all slots of a cluster the SAME teacher key so that replicas
-    stepping on identical leader batches stay bitwise in sync (dropout
-    masks included).
+    in ``agg_row``, never recompiled; DESIGN.md §12).  ``sync_mat`` (S, S)
+    and ``agg_row`` (S,) come from the round's ``RoundPlan`` — they are
+    traced inputs, so sampled participation never recompiles.  ``t_rng`` /
+    ``s_rng`` are one PRNG key per slot.
 
     With ``donate=True`` the per-round SLOT temporaries (tp, ts, sp, ss —
     args 0-3) are donated: XLA updates them in place instead of allocating
@@ -477,57 +570,18 @@ def make_packed_kd_round(mesh, pack: int, t_fwd: Callable, s_fwd: Callable,
     treat those inputs as consumed after the call (the strategies rebuild
     them from canonical state every round, so nothing else holds them; see
     DESIGN.md §13 for the donation contract)."""
-    if kd_impl not in ("fused", "reference"):
-        raise ValueError(
-            f"kd_impl must be 'fused' or 'reference', got {kd_impl!r}")
+    student = _make_student_kd(t_fwd, s_fwd, s_opt, pack, kd_temperature,
+                               kd_alpha, kd_impl)
 
     def kd_round(tp, ts, sp, ss, tx, ty, t_n, sx, sy, s_n, t_rng, s_rng,
                  sync_mat, agg_row):
-        # ---- 1-2: teacher refresh (per lane) + packed sync
-        def t_lane(tp, ts, xs, ys, n, rng):
-            step = _make_teacher_step(t_fwd, t_opt, rng)
-            return _masked_scan_steps(step, (tp, ts), xs, ys, n)
-
-        with jax.named_scope("teacher_phase"):
-            (tp, ts), t_loss = jax.vmap(t_lane)(tp, ts, tx, ty, t_n, t_rng)
+        (tp, ts), t_loss = _teacher_scan(t_fwd, t_opt, tp, ts, tx, ty, t_n,
+                                         t_rng)
         with jax.named_scope("cross_lane"):
             tp = cc.packed_teacher_sync(tp, AXIS, sync_mat, pack=pack)
             ts = cc.packed_teacher_sync(ts, AXIS, sync_mat, pack=pack)
-
-        # ---- 3: student distillation against the synced cluster teacher
-        def s_lane(sp, ss, xs, ys, n, rng, tp):
-            def s_step(carry, batch):
-                p, s = carry
-                x, y, i = batch
-                k = jax.random.fold_in(rng, i)
-                t_logits = t_fwd(tp, x, train=False, key=None)
-
-                def loss_fn(p):
-                    s_logits = s_fwd(p, x, train=True, key=k)
-                    if kd_impl == "fused":
-                        return ops.kd_distillation_loss_batched(
-                            s_logits, t_logits, y,
-                            tau=kd_temperature, alpha=kd_alpha)
-                    return distillation_loss(s_logits, t_logits, y,
-                                             temperature=kd_temperature,
-                                             alpha=kd_alpha)[0]
-
-                loss, g = jax.value_and_grad(loss_fn)(p)
-                u, s = s_opt.update(g, s, p)
-                return (apply_updates(p, u), s), loss
-
-            return _masked_scan_steps(s_step, (sp, ss), xs, ys, n)
-
-        with jax.named_scope("student_kd"):
-            (sp, ss), s_loss = jax.vmap(s_lane)(sp, ss, sx, sy, s_n, s_rng,
-                                                tp)
-
-        # ---- 4: grouped aggregation (plan-weighted mean -> every slot);
-        # the pre-aggregation per-slot students ride along so straggler
-        # lanes can be buffered host-side without a second program
-        sp_local = sp
-        with jax.named_scope("cross_lane"):
-            sp = cc.packed_weighted_mean(sp, AXIS, agg_row, pack=pack)
+        sp, sp_local, ss, s_loss = student(sp, ss, sx, sy, s_n, s_rng, tp,
+                                           agg_row)
         return (tp, ts, sp, sp_local, ss,
                 _active_mean(t_loss, t_n, AXIS),
                 _active_mean(s_loss, s_n, AXIS))
@@ -537,6 +591,60 @@ def make_packed_kd_round(mesh, pack: int, t_fwd: Callable, s_fwd: Callable,
         in_specs=(P(AXIS),) * 12 + (P(), P()),
         out_specs=(P(AXIS),) * 5 + (P(), P()), check_vma=False,
     ), donate_argnums=(0, 1, 2, 3) if donate else ())
+
+
+def make_leader_kd_round(mesh, pack: int, t_fwd: Callable, s_fwd: Callable,
+                         t_opt: Optimizer, s_opt: Optimizer, *,
+                         kd_temperature: float = 2.0, kd_alpha: float = 0.5,
+                         kd_impl: str = "fused", donate: bool = True):
+    """The FedSiKD round for ``teacher_data="leader"`` as ONE jitted
+    program over the packed client mesh.  Every member of a cluster would
+    train the same teacher on the same leader batches with the same key, so
+    the teacher refresh runs once per cluster ROW, not once per slot:
+
+      1. teacher CE steps on each (K,) row's leader feed, ``vmap``-ed over
+         the rows and replicated on every device; a row with a zero budget
+         (no active slot of its cluster in this wave) comes back unchanged
+      2. each lane reads its cluster's refreshed teacher through the (S,)
+         slot->row index ``t_row`` (-1 = idle slot), gathered in-program
+      3. student distillation and grouped aggregation, exactly as in
+         ``make_packed_kd_round``
+
+    Returns round_fn(tp, ts, sp, ss, tx, ty, t_n, sx, sy, s_n, t_rng, s_rng,
+    t_row, agg_row) -> (tp, ts, sp, sp_local, ss, teacher_loss,
+    student_loss).  ``tp``/``ts`` are the canonical (K, ...) teacher stacks
+    in and the refreshed ones out; ``tx``/``ty`` the (K, steps, B, ...)
+    leader feed, ``t_n`` and ``t_rng`` one budget and one key per row — all
+    replicated (``P()``).  The student operands carry the (S,) slot axis as
+    in ``make_packed_kd_round``.  ``teacher_loss`` is the mean over the
+    active slots of their cluster's teacher loss.
+
+    With ``donate=True`` only the student slot temporaries (sp, ss — args
+    2-3) are donated: the teacher stacks are the round-start snapshot every
+    wave reads, and the checkpoint writer may hold them (DESIGN.md §13)."""
+    student = _make_student_kd(t_fwd, s_fwd, s_opt, pack, kd_temperature,
+                               kd_alpha, kd_impl)
+
+    def kd_round(tp, ts, sp, ss, tx, ty, t_n, sx, sy, s_n, t_rng, s_rng,
+                 t_row, agg_row):
+        (tp, ts), t_loss = _teacher_scan(t_fwd, t_opt, tp, ts, tx, ty, t_n,
+                                         t_rng)
+        row = jnp.maximum(t_row, 0)
+        tp_lane = jax.tree_util.tree_map(lambda a: a[row], tp)
+        sp, sp_local, ss, s_loss = student(sp, ss, sx, sy, s_n, s_rng,
+                                           tp_lane, agg_row)
+        lane_n = jnp.where(t_row >= 0, t_n[row], 0)
+        return (tp, ts, sp, sp_local, ss,
+                _active_mean(t_loss[row], lane_n, AXIS),
+                _active_mean(s_loss, s_n, AXIS))
+
+    return jax.jit(jax.shard_map(
+        kd_round, mesh=mesh,
+        in_specs=(P(), P(), P(AXIS), P(AXIS), P(), P(), P(), P(AXIS),
+                  P(AXIS), P(AXIS), P(), P(AXIS), P(AXIS), P()),
+        out_specs=(P(), P()) + (P(AXIS),) * 3 + (P(), P()),
+        check_vma=False,
+    ), donate_argnums=(2, 3) if donate else ())
 
 
 # -------------------------------------------- FedAvg/FedProx packed engine

@@ -105,6 +105,31 @@ def test_committed_certificate_has_the_full_grid():
     assert len(kd["programs"]["kd_round"]["outputs"]) == 7
 
 
+def test_teacher_layouts_certify_their_own_surfaces():
+    """Leader-mode KD programs take the (K, ...) teacher rows and a (S,)
+    slot->row index; cluster-mode ones a teacher replica per slot and the
+    (S, S) sync operator, single-wave only."""
+    from tools.shapecert.cert import CLUSTERS
+    kd_rows = [e for e in _report()["entries"]
+               if e["config"]["engine"] == "sharded"
+               and "teacher_data" in e["config"]]
+    assert {e["config"]["teacher_data"] for e in kd_rows} == \
+        {"leader", "cluster"}
+    for e in kd_rows:
+        S, leader = (e["layout"]["wave_slots"],
+                     e["config"]["teacher_data"] == "leader")
+        kd, phase = e["programs"]["kd_round"], e["programs"]["teacher_phase"]
+        lanes = CLUSTERS if leader else S
+        for prog in (kd, phase):
+            assert prog["inputs"][0]["head"]["w"].startswith(
+                f"float32[{lanes},"), (e["config"], prog["inputs"][0])
+        assert kd["inputs"][12] == (f"int32[{S}]" if leader
+                                    else f"float32[{S},{S}]")
+        assert len(phase["inputs"]) == (6 if leader else 7)
+        if not leader:
+            assert e["layout"]["n_waves"] == 1
+
+
 def test_check_invariants_flags_cohort_dependence():
     from tools.shapecert.cert import check_invariants
     report = _report()

@@ -32,6 +32,7 @@ SALT_INDEX = 2
 # callables they RETURN (FL003 follows the returned callee, not the factory).
 DONATING_FACTORIES = {
     "make_packed_kd_round": (0, 1, 2, 3),
+    "make_leader_kd_round": (2, 3),
     "make_packed_baseline_round": (0, 1),
     "make_packed_teacher_phase": (0, 1),
 }
@@ -308,13 +309,15 @@ def _collect_donors(m: Module) -> dict[str, tuple[int, ...]]:
     Attribute targets are keyed by their bare attribute name so a callee
     assigned in ``_setup_engine`` is recognised at its ``run_round`` call
     site; factory calls donate per DONATING_FACTORIES unless they pass a
-    literal ``donate=False``."""
+    literal ``donate=False``.  Assignments inside ``if``/``else`` blocks
+    count too, and a binding assigned from several donating callables
+    (one per branch) donates the union of their positions."""
     donors: dict[str, tuple[int, ...]] = {}
     scopes = [m.tree.body] + [n.body for n in ast.walk(m.tree)
                               if isinstance(n, (ast.FunctionDef,
                                                 ast.AsyncFunctionDef))]
     for scope in scopes:
-        for stmt in scope:
+        for stmt in _flat_stmts(scope):
             if not isinstance(stmt, ast.Assign):
                 continue
             call = stmt.value
@@ -334,10 +337,11 @@ def _collect_donors(m: Module) -> dict[str, tuple[int, ...]]:
             if not donated:
                 continue
             for t in stmt.targets:
-                if isinstance(t, ast.Name):
-                    donors[t.id] = donated
-                elif isinstance(t, ast.Attribute):
-                    donors[t.attr] = donated
+                key = (t.id if isinstance(t, ast.Name) else t.attr
+                       if isinstance(t, ast.Attribute) else None)
+                if key is not None:
+                    donors[key] = tuple(sorted(
+                        set(donors.get(key, ())) | set(donated)))
     return donors
 
 

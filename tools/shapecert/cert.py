@@ -16,26 +16,29 @@ smoke happens to run.  This module certifies it STATICALLY:
      from the same layout math the strategies use (``fed_wave_layout``
      + ``jax.eval_shape`` over the model/optimizer inits) and abstractly
      evaluates the REAL round-program factories
-     (``make_packed_kd_round`` / ``make_packed_baseline_round`` /
-     ``make_packed_teacher_phase``) on a real host-device mesh.  No
+     (``make_leader_kd_round`` / ``make_leader_teacher_phase`` for
+     ``teacher_data="leader"``, ``make_packed_kd_round`` /
+     ``make_packed_teacher_phase`` for ``"cluster"``,
+     ``make_packed_baseline_round``) on a real host-device mesh.  No
      datasets are loaded and nothing is compiled or executed.
   3. ``check_invariants`` groups the report by everything that IS
-     allowed to shape a program — (algorithm, engine, pack, wave_slots,
-     steps, batch, kd_impl, donate) — and fails if two entries in one
-     group (i.e. differing only in cohort / universe / waves / async /
-     guards) disagree on any program's input or output shapes.
+     allowed to shape a program — (algorithm, engine, teacher_data, pack,
+     wave_slots, steps, batch, kd_impl, donate) — and fails if two entries
+     in one group (i.e. differing only in cohort / universe / waves /
+     async / guards) disagree on any program's input or output shapes.
 
 CI commits the canonical JSON as ``SHAPES.json`` and diffs every PR
 against it (``python -m tools.shapecert --check SHAPES.json``): a change
 that widens the compile surface or couples it to the cohort fails the
 build before it can fail a profile.
 
-Two modelling constants, both deliberately cohort-independent in the
+Three modelling constants, all deliberately cohort-independent in the
 real runtime and therefore safe to pin here: the scan length ``STEPS``
 (derives from the BASE data pool and batch size, never the universe —
-``stack_client_data`` pads every client to one cap) and the single
-certification dataset (mnist; the model only changes leaf shapes, not
-which dimensions exist).
+``stack_client_data`` pads every client to one cap), the teacher count
+``CLUSTERS`` (fixed at setup by clustering the data pool, and kept
+through every re-clustering) and the single certification dataset
+(mnist; the model only changes leaf shapes, not which dimensions exist).
 """
 from __future__ import annotations
 
@@ -61,6 +64,11 @@ from repro.optim import adamw
 # data pool, NOT the cohort — so any fixed value certifies the same
 # coupling structure.  Small keeps abstract tracing fast.
 STEPS = 2
+# Teacher rows K of the leader-mode programs: set by clustering the data
+# pool at setup and fixed for the run, never by the cohort.
+CLUSTERS = 2
+
+KD_ALGORITHMS = ("fedsikd", "random")
 
 
 # --------------------------------------------------------------- the grid
@@ -89,6 +97,15 @@ def build_grid() -> list[FedConfig]:
                       **base),
             FedConfig(algorithm=algorithm, universe=16, waves=4,
                       guards="jitter", **base),
+        ]
+    # cluster-pooled teachers (per-slot replicas + sync) need the whole
+    # cohort on the mesh at once: single-wave rows only
+    for algorithm in KD_ALGORITHMS:
+        grid += [
+            FedConfig(algorithm=algorithm, teacher_data="cluster", **base),
+            FedConfig(algorithm=algorithm, teacher_data="cluster",
+                      async_mode=True, straggler_frac=0.5, guards=True,
+                      **base),
         ]
     for algorithm in ("fedsikd", "random", "fedavg", "fedprox", "flhc"):
         grid.append(FedConfig(algorithm=algorithm, engine="loop",
@@ -159,6 +176,8 @@ def certify_config(cfg: FedConfig, *, dataset: str = "mnist",
         },
         "programs": {},
     }
+    if cfg.algorithm in KD_ALGORITHMS:
+        entry["config"]["teacher_data"] = cfg.teacher_data
     if cfg.engine != "sharded":
         entry["layout"] = None      # no packed mesh, no compiled surface
         return entry
@@ -177,25 +196,44 @@ def certify_config(cfg: FedConfig, *, dataset: str = "mnist",
     agg_row = jax.ShapeDtypeStruct((S,), jnp.float32)
     programs = entry["programs"]
 
-    if cfg.algorithm in ("fedsikd", "random"):
+    if cfg.algorithm in KD_ALGORITHMS:
         tp1, t_fwd = _model_avals(dataset, student=False)
         sp1, s_fwd = _model_avals(dataset, student=True)
         t_opt, s_opt = adamw(cfg.lr), adamw(cfg.student_lr)
-        tp = _stack(tp1, S)
-        ts = _opt_state_avals(t_opt, tp)
         sp = _stack(sp1, S)
         ss = _opt_state_avals(s_opt, sp)
-        kd_round = sh.make_packed_kd_round(
-            mesh, cfg.pack, t_fwd, s_fwd, t_opt, s_opt,
-            kd_temperature=cfg.kd_temperature, kd_alpha=cfg.kd_alpha,
-            kd_impl=cfg.kd_impl, donate=cfg.donate)
-        programs["kd_round"] = _record(
-            kd_round, tp, ts, sp, ss, xs, ys, n_steps, xs, ys, n_steps,
-            rng, rng, sync_mat, agg_row)
-        phase = sh.make_packed_teacher_phase(
-            mesh, cfg.pack, t_fwd, t_opt, donate=cfg.donate)
-        programs["teacher_phase"] = _record(
-            phase, tp, ts, xs, ys, n_steps, rng, sync_mat)
+        kd = dict(kd_temperature=cfg.kd_temperature, kd_alpha=cfg.kd_alpha,
+                  kd_impl=cfg.kd_impl, donate=cfg.donate)
+        if cfg.teacher_data == "leader":
+            # one teacher per cluster row: (K, ...) stacks, leader feed,
+            # budgets and keys; each slot's row index
+            tp = _stack(tp1, CLUSTERS)
+            ts = _opt_state_avals(t_opt, tp)
+            txs, tys = _data_avals(dataset, CLUSTERS, cfg.batch_size)
+            t_n = jax.ShapeDtypeStruct((CLUSTERS,), jnp.int32)
+            t_rng = jax.ShapeDtypeStruct((CLUSTERS, 2), jnp.uint32)
+            t_row = jax.ShapeDtypeStruct((S,), jnp.int32)
+            kd_round = sh.make_leader_kd_round(
+                mesh, cfg.pack, t_fwd, s_fwd, t_opt, s_opt, **kd)
+            programs["kd_round"] = _record(
+                kd_round, tp, ts, sp, ss, txs, tys, t_n, xs, ys, n_steps,
+                t_rng, rng, t_row, agg_row)
+            phase = sh.make_leader_teacher_phase(mesh, t_fwd, t_opt)
+            programs["teacher_phase"] = _record(
+                phase, tp, ts, txs, tys, t_n, t_rng)
+        else:
+            # a teacher replica on every slot, synced per cluster
+            tp = _stack(tp1, S)
+            ts = _opt_state_avals(t_opt, tp)
+            kd_round = sh.make_packed_kd_round(
+                mesh, cfg.pack, t_fwd, s_fwd, t_opt, s_opt, **kd)
+            programs["kd_round"] = _record(
+                kd_round, tp, ts, sp, ss, xs, ys, n_steps, xs, ys, n_steps,
+                rng, rng, sync_mat, agg_row)
+            phase = sh.make_packed_teacher_phase(
+                mesh, cfg.pack, t_fwd, t_opt, donate=cfg.donate)
+            programs["teacher_phase"] = _record(
+                phase, tp, ts, xs, ys, n_steps, rng, sync_mat)
     else:                                   # fedavg | fedprox
         p1, fwd = _model_avals(dataset, student=False)
         opt = adamw(cfg.lr)
@@ -237,9 +275,9 @@ def _surface_key(entry) -> tuple:
     waves, async and guards are deliberately absent: entries differing
     only in those must certify identical surfaces."""
     c, lay = entry["config"], entry["layout"]
-    return (c["algorithm"], c["engine"], c["pack"], lay["wave_slots"],
-            c["batch_size"], c["steps"], c["kd_impl"], c["donate"],
-            c["dataset"])
+    return (c["algorithm"], c["engine"], c.get("teacher_data"), c["pack"],
+            lay["wave_slots"], c["batch_size"], c["steps"], c["kd_impl"],
+            c["donate"], c["dataset"])
 
 
 def check_invariants(report: dict) -> list[str]:

@@ -1,9 +1,10 @@
-"""Benchmark harness entrypoint — one section per paper table/figure plus
-the systems benchmarks.  Prints ``name,us_per_call,derived`` CSV-ish lines.
+"""Accuracy benchmarks: one section per paper table/figure.  Speed is
+measured on the chip by ``bench/run.py`` and recorded in
+``PERF_LEDGER.jsonl``, not here.
 
   PYTHONPATH=src python -m benchmarks.run            # quick mode
   PYTHONPATH=src python -m benchmarks.run --full     # paper-scale alphas
-  PYTHONPATH=src python -m benchmarks.run --only kernels,roofline
+  PYTHONPATH=src python -m benchmarks.run --only clustering,dp
 """
 from __future__ import annotations
 
@@ -16,7 +17,7 @@ def main() -> None:
     ap.add_argument("--full", action="store_true",
                     help="all four alpha levels, full-size twins")
     ap.add_argument("--only", default=None,
-                    help="comma list: tables,kernels,clustering,roofline,dp")
+                    help="comma list: tables,clustering,dp")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
@@ -24,18 +25,10 @@ def main() -> None:
         return only is None or name in only
 
     t0 = time.time()
-    if want("kernels"):
-        print("== kernel micro-benchmarks (Pallas refs; TPU HBM models) ==")
-        from benchmarks import kernels_bench
-        kernels_bench.main()
     if want("clustering"):
         print("== clustering quality (paper §IV-A: metric-voted K) ==")
         from benchmarks import clustering_bench
         clustering_bench.main(quick=not args.full)
-    if want("roofline"):
-        print("== roofline table (§Roofline; single-pod 16x16) ==")
-        from benchmarks import roofline_bench
-        roofline_bench.main()
     if want("dp"):
         print("== DP-noise ablation (beyond paper; cached) ==")
         import json, pathlib

@@ -1,6 +1,6 @@
 """FedSiKD at LLM scale: cluster-parallel teacher->student distillation with
-the exact step the multi-pod dry-run lowers (launch/steps.py
-make_fedsikd_distill_step), on 8 placeholder devices with a reduced config.
+``launch/steps.make_fedsikd_distill_step``, on 8 placeholder devices with a
+reduced config.
 
 4 client replicas (dp axis) in 2 clusters distill a frozen full-depth
 teacher into depth-pruned students; intra-cluster gradient aggregation is

@@ -1,6 +1,6 @@
 """Serve a reduced model with batched requests: prefill (returns last logits
-+ KV cache) then greedy decode continuation — the same prefill/decode steps
-the dry-run lowers at 32k/500k scale.
++ KV cache) then greedy decode continuation (``launch/steps.py``'s prefill
+and decode steps).
 
   PYTHONPATH=src python examples/serve.py [arch]
 """

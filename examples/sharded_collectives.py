@@ -8,8 +8,7 @@ clients==devices coupling: 24 clients packed 3-per-device with stratified
 partial participation (12 sampled clients per round) through the same
 jitted program.  Part 4 runs a BASELINE (FedAvg) through the same packed
 runtime — since the algorithm-strategy layer, the paper's comparison
-algorithms share the mesh engine.  This is the communication pattern the
-multi-pod dry-run scales up.
+algorithms share the mesh engine.
 
   JAX_PLATFORMS=cpu PYTHONPATH=src python examples/sharded_collectives.py
 """

@@ -1,3 +1,3 @@
-from repro.configs.base import ARCH_IDS, INPUT_SHAPES, ModelConfig, get_config, list_archs
+from repro.configs.base import ARCH_IDS, ModelConfig, get_config, list_archs
 
-__all__ = ["ARCH_IDS", "INPUT_SHAPES", "ModelConfig", "get_config", "list_archs"]
+__all__ = ["ARCH_IDS", "ModelConfig", "get_config", "list_archs"]

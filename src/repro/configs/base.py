@@ -23,14 +23,6 @@ ARCH_IDS = [
     "nemotron-4-340b",
 ]
 
-INPUT_SHAPES = {
-    "train_4k": dict(seq_len=4096, global_batch=256, kind="train"),
-    "prefill_32k": dict(seq_len=32768, global_batch=32, kind="prefill"),
-    "decode_32k": dict(seq_len=32768, global_batch=128, kind="decode"),
-    "long_500k": dict(seq_len=524288, global_batch=1, kind="decode"),
-}
-
-
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
@@ -58,7 +50,7 @@ class ModelConfig:
                                    # one-hot baseline; §Perf before-state)
     # >1: group-local dispatch aligned with the dp shards (hillclimb A) —
     # scatter/gather stay shard-local, cross-shard movement becomes ONE
-    # buffer all-to-all.  Set by the launcher to the dp axis size.
+    # buffer all-to-all.
     moe_groups: int = 1
     # --- MLA (deepseek) ---
     use_mla: bool = False
@@ -85,9 +77,6 @@ class ModelConfig:
     # --- numerics / training ---
     dtype: str = "bfloat16"
     remat: bool = True
-    # analysis probes: unroll layer scans so compiled cost_analysis counts
-    # every layer (XLA counts while-loop bodies ONCE; see launch/roofline.py)
-    unroll: bool = False
     # KD student derivation: student keeps every k-th layer
     student_layer_keep: float = 0.5
 
@@ -105,7 +94,7 @@ class ModelConfig:
                                    name=self.name + "-student")
 
     def param_count(self) -> int:
-        """Analytic parameter count (used for 6ND model-FLOPs in roofline)."""
+        """Analytic parameter count."""
         d, L, V = self.d_model, self.num_layers, self.vocab_size
         hd = self.hd
         if self.use_mla:

@@ -71,7 +71,6 @@ from repro.core.distill import distillation_loss, softmax_cross_entropy
 from repro.fed.schedule import RoundPlan
 from repro.kernels import ops
 from repro.launch.mesh import CLIENT_AXIS, make_fed_client_mesh
-from repro.launch.shardings import client_stack_specs, named
 from repro.optim import Optimizer, apply_updates, fedprox_penalty
 
 AXIS = CLIENT_AXIS
@@ -156,98 +155,27 @@ def stage_on_slots(mesh, plan: RoundPlan, *arrays, row_maps=None,
                 np.asarray(a)[cid if m is None else np.asarray(m)[cid]])
             for a, m in zip(arrays, maps))
         perf.count_bytes("h2d_bytes", *stacks, round_id=round_id)
-        return jax.device_put(stacks, named(mesh, client_stack_specs(
-            stacks, mesh, axis=AXIS)))
-
-
-class SlotStager:
-    """Caches the row-gathered slot staging of ``arrays`` across rounds,
-    restaging only when the plan's slot->client assignment changes (with
-    ``participation="full"`` it never does: one upload total).
-
-    ``prefetch(plan)`` overlaps the NEXT round's staging with the current
-    round's device compute: the host-side row-gather + ``device_put`` run on
-    a background thread keyed by the plan's slot assignment, and ``stage``
-    joins and adopts the result when the key matches.  A mispredicted
-    prefetch (lifecycle re-clustered, scheduler rebuilt) is simply
-    discarded and ``stage`` falls back to the synchronous path — prefetch
-    is an overlap optimisation, never a source of truth."""
-
-    def __init__(self, mesh, *arrays):
-        self.mesh, self.arrays = mesh, arrays
-        self._key = None
-        self._staged = None
-        self._pending = None        # (key, thread, result box)
-
-    def stage(self, plan: RoundPlan):
-        key = plan.slot_client.tobytes()
-        if key == self._key:
-            return self._staged
-        staged = self._take_pending(key)
-        if staged is None:
-            staged = stage_on_slots(self.mesh, plan, *self.arrays)
-        self._key, self._staged = key, staged
-        return staged
-
-    def prefetch(self, plan: RoundPlan):
-        """Begin staging ``plan``'s slot arrays on a background thread (no-op
-        if that assignment is already staged or already in flight)."""
-        key = plan.slot_client.tobytes()
-        if key == self._key or (self._pending is not None
-                                and self._pending[0] == key):
-            return
-        self._drop_pending()
-        box = {}
-        token = perf.round_token()
-
-        def work():
-            guards.jitter_point("slot-prefetch")
-            try:
-                box["staged"] = stage_on_slots(self.mesh, plan, *self.arrays,
-                                               round_id=token)
-            except Exception as e:   # pragma: no cover - surfaced via fallback
-                box["error"] = e
-
-        th = threading.Thread(target=work, daemon=True, name="slot-prefetch")
-        th.start()
-        self._pending = (key, th, box)
-
-    def _take_pending(self, key):
-        if self._pending is None or self._pending[0] != key:
-            # not what this round needs (e.g. the NEXT round's prefetch is
-            # already in flight): leave it pending, stage synchronously
-            return None
-        _, th, box = self._pending
-        self._pending = None
-        guards.jitter_point("slot-stage")
-        th.join()
-        return box.get("staged")     # error -> None -> sync retry raises it
-
-    def _drop_pending(self):
-        # An abandoned prefetch thread just finishes and its result is GC'd.
-        self._pending = None
+        # the leading (S,) slot axis over the client axis, the rest whole
+        return jax.device_put(stacks, tuple(
+            NamedSharding(mesh, P(AXIS, *([None] * (a.ndim - 1))))
+            for a in stacks))
 
 
 class WaveStager:
-    """Multi-wave generalisation of ``SlotStager`` (DESIGN.md §15): an LRU
-    cache of staged wave assignments plus a DICT of in-flight prefetches,
-    so wave ``w+1``'s host gather + ``device_put`` runs on a background
-    thread while wave ``w`` computes, and the next round's wave-0 prefetch
-    coexists with this round's in-flight waves (the single-pending
-    ``SlotStager`` dropped whichever came second).
+    """The one stager of the packed runtime (DESIGN.md §15): an LRU cache
+    of staged wave assignments plus a DICT of in-flight prefetches, so wave
+    ``w+1``'s host gather + ``device_put`` runs on a background thread
+    while wave ``w`` computes, and the next round's wave-0 prefetch
+    coexists with this round's in-flight waves.
 
     ``capacity`` bounds the staged cache — size it ``n_waves + 1`` so a
     whole round's waves plus the next round's wave-0 prefetch fit; a full
     cache evicts least-recently-used (repeat assignments across rounds,
-    e.g. ``participation="full"`` single-wave, then never re-upload,
-    preserving SlotStager's one-upload behaviour).
+    e.g. ``participation="full"`` single-wave, then never re-upload: one
+    upload total).
 
-    Overlap accounting (``perf``): adopting a prefetched wave records the
-    background gather time that ran hidden behind compute
-    (``stage_hidden``) and the residual join wait (``stage_wait``); a
-    cold/mispredicted wave records its full synchronous gather as
-    ``stage_wait``.  ``overlap_efficiency = hidden / (hidden + wait)``
-    (benchmarks/engine_bench.py)."""
+    ``perf``'s ``stage_wait`` records the seconds ``stage`` blocks: the
+    join on a prefetched wave, or a cold wave's whole synchronous gather."""
 
     def __init__(self, mesh, *arrays,
                  row_maps: Optional[Sequence] = None, capacity: int = 2):
@@ -281,8 +209,6 @@ class WaveStager:
             wait = time.perf_counter() - t0
             staged = box.get("staged")
             if staged is not None:
-                perf.add("stage_hidden",
-                         max(0.0, box.get("dt", 0.0) - wait))
                 perf.add("stage_wait", wait)
                 self._put(key, staged)
                 return staged
@@ -307,12 +233,10 @@ class WaveStager:
 
         def work():
             guards.jitter_point("wave-prefetch")
-            t0 = time.perf_counter()
             try:
                 box["staged"] = self._gather(plan, round_id=token)
             except Exception as e:  # pragma: no cover - raised on sync retry
                 box["error"] = e
-            box["dt"] = time.perf_counter() - t0
 
         th = threading.Thread(target=work, daemon=True, name="wave-prefetch")
         th.start()

@@ -8,7 +8,7 @@ assertions, all enabled together by ``FedConfig.guards``:
   ``transfer_guard("disallow")``: any implicit host->device transfer
   inside the block (a numpy array or Python scalar silently fed to a
   jitted program) raises instead of quietly re-staging a copy every
-  round.  The hot path must stage through ``SlotStager`` / explicit
+  round.  The hot path must stage through ``WaveStager`` / explicit
   ``jax.device_put`` — this guard is what makes "must" mean something.
   (``jnp.asarray`` does NOT count as explicit: its transfer is async and
   the guard fires when the result is consumed.)

@@ -1,6 +1,5 @@
-"""Production mesh construction (TPU v5e pods; CPU placeholder devices for
-the dry-run) plus the federated client-mesh layout.  FUNCTIONS, not module
-constants — importing this module must never touch jax device state.
+"""The federated client-mesh layout and its 1-D mesh.  FUNCTIONS, not
+module constants — importing this module must never touch jax device state.
 """
 from __future__ import annotations
 
@@ -9,9 +8,6 @@ import math
 import jax
 import numpy as np
 from jax.sharding import Mesh
-
-SINGLE_POD = (16, 16)                 # 256 chips
-MULTI_POD = (2, 16, 16)               # 2 pods x 256 chips
 
 CLIENT_AXIS = "clients"               # the federated engines' 1-D mesh axis
 
@@ -88,21 +84,3 @@ def make_fed_client_mesh(n_participants: int, *, pack: int = 1,
             "before importing jax, or raise pack")
     return Mesh(np.asarray(devs[:n_devices]), (CLIENT_AXIS,))
 
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = MULTI_POD if multi_pod else SINGLE_POD
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes,
-                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-
-
-def dp_axes(mesh) -> tuple[str, ...]:
-    """Axes that shard batch/clients (and FSDP params)."""
-    return (("pod", "data") if "pod" in mesh.axis_names else ("data",))
-
-
-def axis_size(mesh, names) -> int:
-    s = 1
-    for n in (names if isinstance(names, (tuple, list)) else (names,)):
-        s *= mesh.shape[n]
-    return s

@@ -1,4 +1,4 @@
-"""Step builders lowered by the dry-run and used by launch/train.py:
+"""Step builders used by launch/train.py and the LM examples:
 
   - train_step          — LM next-token training (AdamW, optional grad accum)
   - prefill_step        — serving prefill: last logits + decode cache
@@ -27,7 +27,7 @@ def _loss_mod(cfg: ModelConfig):
 
 
 def make_optimizer(cfg: ModelConfig, *, lr: float = 1e-4):
-    """bf16 moments above the FSDP threshold (HBM; DESIGN.md §5)."""
+    """bf16 moments above 8B params (HBM; DESIGN.md §4–§5)."""
     big = cfg.param_count() > 8_000_000_000
     return adamw(lr, state_dtype=jnp.bfloat16 if big else jnp.float32)
 
@@ -66,9 +66,8 @@ def make_train_step(cfg: ModelConfig, *, lr: float = 1e-4, accum: int = 1):
     # Donation contract (DESIGN.md §13): (params, opt_state) flow through
     # the step unchanged in shape/sharding, so jit sites can donate them and
     # update in place instead of holding two copies of the model.  The
-    # builders return UN-jitted steps (the dry-run lowers them with explicit
-    # shardings), so donation rides along as an attribute for the jit site
-    # (launch/train.py, launch/dryrun.py) to consume.
+    # builders return UN-jitted steps, so donation rides along as an
+    # attribute for the jit site (launch/train.py) to consume.
     train_step.donate_argnums = (0, 1)
     return train_step, opt
 

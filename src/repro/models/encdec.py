@@ -82,7 +82,7 @@ def encode(p, cfg: ModelConfig, frames: jax.Array):
         x = x + ly.mlp_fwd(lp["mlp"], cfg, ly.rmsnorm(lp["ln2"], x, cfg.rms_eps))
         return x, 0.0
 
-    x, _ = jax.lax.scan(_maybe_remat(cfg, body), x, p["encoder"], unroll=cfg.unroll)
+    x, _ = jax.lax.scan(_maybe_remat(cfg, body), x, p["encoder"])
     return x
 
 
@@ -102,7 +102,7 @@ def forward(p, cfg: ModelConfig, batch: dict):
         x = x + ly.mlp_fwd(lp["mlp"], cfg, ly.rmsnorm(lp["ln2"], x, cfg.rms_eps))
         return x, 0.0
 
-    x, _ = jax.lax.scan(_maybe_remat(cfg, body), x, p["decoder"], unroll=cfg.unroll)
+    x, _ = jax.lax.scan(_maybe_remat(cfg, body), x, p["decoder"])
     return _logits(p, cfg, x), jnp.float32(0.0)
 
 
@@ -134,7 +134,7 @@ def decode_step(p, cfg: ModelConfig, cache, tokens, pos):
         x = x + ly.mlp_fwd(lp["mlp"], cfg, ly.rmsnorm(lp["ln2"], x, cfg.rms_eps))
         return x, (nk, nv)
 
-    x, (nk, nv) = jax.lax.scan(body, x, (p["decoder"], cache["k"], cache["v"]), unroll=cfg.unroll)
+    x, (nk, nv) = jax.lax.scan(body, x, (p["decoder"], cache["k"], cache["v"]))
     new = {"memory": memory, "k": nk, "v": nv}
     return _logits(p, cfg, x), new
 

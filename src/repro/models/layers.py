@@ -3,7 +3,7 @@ attention (train, prefill and one-token decode paths), dense MLPs
 (SwiGLU / GELU / squared-ReLU) and capacity-based MoE.
 
 Functional style: ``init_*`` builds a param dict (traceable, so
-``jax.eval_shape`` gives allocation-free ShapeDtypeStructs for the dry-run),
+``jax.eval_shape`` gives allocation-free ShapeDtypeStructs),
 ``*_fwd`` applies it.  Per-layer params are stacked on a leading L axis by the
 model wrappers and consumed through ``jax.lax.scan``.
 """

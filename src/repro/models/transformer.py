@@ -129,7 +129,7 @@ def forward(p, cfg: ModelConfig, batch: dict, *, window: int | None = None,
             out, carries = rk.rwkv6_block_fwd(lp, cfg, x, tm_prev=zero_prev,
                                               cm_prev=zero_prev)
             return out, (carries if return_cache else 0.0)
-        x, ys = jax.lax.scan(_maybe_remat(cfg, body), x, p["layers"], unroll=cfg.unroll)
+        x, ys = jax.lax.scan(_maybe_remat(cfg, body), x, p["layers"])
         if return_cache:
             return _out(x), ys
         return _out(x), jnp.float32(0.0)
@@ -158,7 +158,7 @@ def forward(p, cfg: ModelConfig, batch: dict, *, window: int | None = None,
                                ly.rmsnorm(shared["ln2"], x, cfg.rms_eps))
             ys = (sts, trim(k), trim(v)) if return_cache else 0.0
             return x, ys
-        x, ys = jax.lax.scan(_maybe_remat(cfg, group), x, stacked, unroll=cfg.unroll)
+        x, ys = jax.lax.scan(_maybe_remat(cfg, group), x, stacked)
         if return_cache:
             return _out(x), ys
         return _out(x), jnp.float32(0.0)
@@ -183,7 +183,7 @@ def forward(p, cfg: ModelConfig, batch: dict, *, window: int | None = None,
                 aux = {"k": trim(kv[0]), "v": trim(kv[1])}
         return x + m, aux
 
-    x, auxs = jax.lax.scan(_maybe_remat(cfg, body), x, p["layers"], unroll=cfg.unroll)
+    x, auxs = jax.lax.scan(_maybe_remat(cfg, body), x, p["layers"])
     if return_cache:
         return _out(x), auxs
     return _out(x), jnp.sum(auxs)
@@ -236,7 +236,7 @@ def decode_step(p, cfg: ModelConfig, cache, tokens, pos):
             lp, c = sc
             out, nc = rk.rwkv6_block_decode(lp, cfg, x, c)
             return out, nc
-        x, new = jax.lax.scan(body, x, (p["layers"], cache), unroll=cfg.unroll)
+        x, new = jax.lax.scan(body, x, (p["layers"], cache))
         return _logits(p, cfg, x), new
 
     if cfg.arch_type == "hybrid":
@@ -292,7 +292,7 @@ def decode_step(p, cfg: ModelConfig, cache, tokens, pos):
             m = ly.mlp_fwd(lp["mlp"], cfg, h)
         return x + m, newc
 
-    x, new = jax.lax.scan(body, x, (p["layers"], cache), unroll=cfg.unroll)
+    x, new = jax.lax.scan(body, x, (p["layers"], cache))
     return _logits(p, cfg, x), new
 
 

@@ -66,7 +66,7 @@ def adamw(
     state_dtype=jnp.float32,
 ) -> Optimizer:
     """AdamW.  ``state_dtype=bfloat16`` halves optimizer-state HBM for the
-    giant assigned archs (used by the FSDP configs; see DESIGN.md §5)."""
+    giant assigned archs (``launch/steps.make_optimizer``, above 8B params)."""
     lr_fn = lr if callable(lr) else (lambda _: lr)
 
     def init(params):

@@ -3,16 +3,11 @@ import jax.numpy as jnp
 
 
 def bad_cache_key(arr, table):
-    return table[arr.tobytes()]     # VIOLATION: tobytes key outside SlotStager
+    return table[arr.tobytes()]     # VIOLATION: tobytes key outside WaveStager
 
 
 def bad_shape(items):
     return jnp.stack([jnp.zeros(3) for _ in items])   # VIOLATION: comprehension shape
-
-
-class SlotStager:
-    def stage(self, plan):
-        return plan.slot_client.tobytes()     # ok: the blessed staging path
 
 
 class WaveStager:

@@ -118,12 +118,12 @@ def test_fl005_catches_tobytes_key_and_comprehension_shape():
     assert_seeded_violations_caught("fl005", "FL005", "fed/recompile.py")
 
 
-def test_fl005_blesses_both_stagers():
-    # the fixture's WaveStager/SlotStager bodies key on .tobytes() with no
-    # VIOLATION marker — assert_seeded_violations_caught above proves they
-    # are NOT flagged; this pins the blessed set itself
+def test_fl005_blesses_the_stager():
+    # the fixture's WaveStager body keys on .tobytes() with no VIOLATION
+    # marker — assert_seeded_violations_caught above proves it is NOT
+    # flagged; this pins the blessed set itself
     from tools.fedlint.rules import BLESSED_STAGERS
-    assert BLESSED_STAGERS == frozenset({"SlotStager", "WaveStager"})
+    assert BLESSED_STAGERS == frozenset({"WaveStager"})
 
 
 def test_fl006_catches_unlocked_thread_shared_writes():

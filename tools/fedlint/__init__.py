@@ -21,8 +21,8 @@ them down (DESIGN.md §14):
                                   ``np.*`` on traced values inside jitted /
                                   ``shard_map``-ped / Pallas code.
   FL005  recompile safety         no ``.tobytes()``-keyed structures outside
-                                  the blessed staging classes (``SlotStager``
-                                  / ``WaveStager``), no Python-value-dependent
+                                  the blessed staging class
+                                  (``WaveStager``), no Python-value-dependent
                                   array shapes (comprehension-shaped
                                   constructors) feeding jitted programs.
 

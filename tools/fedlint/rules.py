@@ -833,8 +833,8 @@ def _expr_tainted(expr: ast.AST, tainted: set[str]) -> bool:
 # ========================================================= FL005: recompiles
 # Classes whose bodies may key on ``.tobytes()``: the staging path is the
 # ONE place a plan's slot assignment legitimately becomes a cache key
-# (SlotStager's per-round memo, WaveStager's per-wave LRU + prefetch boxes).
-BLESSED_STAGERS = frozenset({"SlotStager", "WaveStager"})
+# (WaveStager's per-wave LRU + prefetch boxes).
+BLESSED_STAGERS = frozenset({"WaveStager"})
 
 
 def check_fl005(project: Project) -> list[Finding]:
@@ -858,7 +858,7 @@ def check_fl005(project: Project) -> list[Finding]:
                 findings.append(Finding(
                     "FL005", m.rel, node.lineno,
                     ".tobytes()-keyed structure outside the blessed "
-                    "staging path (fed/sharded.py SlotStager/WaveStager) "
+                    "staging path (fed/sharded.py WaveStager) "
                     "— ad-hoc byte keys feeding jit arguments are the "
                     "recompile bug class"))
             seg = last_segment(node.func)
@@ -1183,7 +1183,7 @@ RULE_DOCS = {
     "FL004": "tracer safety: no if/float()/.item()/np.* on traced values in"
              " traced code",
     "FL005": "recompile safety: no .tobytes() keys outside the blessed"
-             " stagers (SlotStager/WaveStager), no comprehension-shaped jnp"
+             " stager (WaveStager), no comprehension-shaped jnp"
              " constructors",
     "FL006": "lock discipline: attributes mutated from both a worker thread"
              " and main-thread methods must be written under a held lock or"

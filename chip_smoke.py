@@ -125,8 +125,8 @@ def timed_run(ds, cfg, label):
         wall = time.perf_counter() - t0
         buckets = perf.snapshot()
         perf.disable()
-    rounds = [b.get("round_total", 0.0) + b.get("eval", 0.0)
-              + b.get("checkpoint", 0.0) for b in buckets]
+    rounds = [sum(b.get(k, 0.0) for k in ("round_total", "eval", "settle",
+                                          "checkpoint")) for b in buckets]
     steady = float(np.median(rounds[1:])) if len(rounds) > 1 else 0.0
     print(f"{label}: setup_s={wall - sum(rounds):.3f} "
           f"round1_compile_s={max(rounds[0] - steady, 0.0):.3f} "

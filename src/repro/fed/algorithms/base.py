@@ -27,6 +27,11 @@ Lifecycle (driven by ``RoundDriver.run``):
 4. ``eval()`` — (accuracy, loss) of the algorithm's CURRENT global model on
    the test set; the driver records it after every round, identically for
    every algorithm (acc AND loss — no more per-algorithm reporting drift).
+
+   Both may hand back 0-d device values instead of host floats: the driver
+   reads a round's values after it has dispatched the next round
+   (DESIGN.md §13), so the host plans and stages round r+1 while the
+   device still runs round r.
 5. ``checkpoint_arrays()`` / ``restore_arrays(arrays)`` — the array pytree
    that crosses the round boundary (exactly what ``fedstate.FedState``
    persists) and its inverse.  The driver owns WHEN to save/restore; the
@@ -62,6 +67,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import perf
 from repro.core import aggregation as agg
 from repro.data.pipeline import ClientShard
 from repro.fed.client import stage_test_set
@@ -202,6 +208,27 @@ def packed_async_row(w_slot, on_time, arrivals, decay: float):
     scales = [float(u.weight) * float(fi) / total
               for u, fi in zip(arrivals, f)]
     return (w / total).astype(np.float32), scales
+
+
+def fold_losses(vals):
+    """One mean of per-wave ``(loss, count)`` active-slot means, weighted by
+    the counts, on the device (each weight is an explicit transfer to the
+    loss's own placement, legal under the guards).  A single contributing
+    wave passes its loss through UNTOUCHED: the single-wave path stays
+    bit-identical to the monolithic round."""
+    vals = [(lo, int(c)) for lo, c in vals if c > 0]
+    if not vals:
+        return 0.0
+    if len(vals) == 1:
+        return vals[0][0]
+    tot = sum(c for _, c in vals)
+    out = None
+    for lo, c in vals:
+        w = np.float32(c / tot)
+        perf.count_bytes("h2d_bytes", w)
+        term = lo * jax.device_put(w, lo.sharding)
+        out = term if out is None else out + term
+    return out
 
 
 def merge_arrivals_only(arrivals, decay: float):

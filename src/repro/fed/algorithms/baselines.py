@@ -30,9 +30,10 @@ from repro import perf
 from repro.core import aggregation as agg
 from repro.data.pipeline import ClientStore
 from repro.fed import schedule
-from repro.fed.algorithms.base import (Algorithm, local_epochs,
-                                       merge_arrivals_only, packed_async_row,
-                                       staleness_merge, tree_copy)
+from repro.fed.algorithms.base import (Algorithm, fold_losses,
+                                       local_epochs, merge_arrivals_only,
+                                       packed_async_row, staleness_merge,
+                                       tree_copy)
 from repro.fed.driver import AsyncUpdate
 from repro.fed.client import evaluate, make_steps
 from repro.models.cnn import make_model
@@ -299,11 +300,7 @@ class PackedBaseline(_BaselineBase):
                         self.global_params)
                 if w + 1 < n_waves:
                     self.stager.prefetch(plan.wave(w + 1))
-                # the loss read blocks until the wave's program ends
-                with perf.span("sync"):
-                    perf.count("host_syncs")
-                    loss = float(loss)
-                losses.append((loss, int((n_w > 0).sum())))
+                losses.append((loss, (n_w > 0).sum()))
             with perf.span("aggregate"):
                 # every slot holds the wave's partial aggregate after the
                 # (globally-weighted) contraction
@@ -315,11 +312,8 @@ class PackedBaseline(_BaselineBase):
                         arrival=rnd + int(wp.delays[t]),
                         weight=float(self.sizes[int(wp.slot_client[t])]),
                         params=sh.take_rows(p_local, jax.device_put(int(t)))))
-        if len(losses) == 1:
-            loss = losses[0][0]
-        else:
-            tot = sum(c for _, c in losses)
-            loss = float(sum(lo * c for lo, c in losses) / tot) if tot else 0.0
+        # a device value: the driver reads it a round later
+        loss = fold_losses(losses)
         p0 = partials[0] if len(partials) == 1 else agg.fold_partials(partials)
         if not has_async:
             self.global_params = p0

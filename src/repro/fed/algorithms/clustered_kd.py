@@ -39,9 +39,9 @@ from repro.core import aggregation as agg
 from repro.core import kmeans, stats
 from repro.fed import schedule
 from repro.fed.algorithms.base import (Algorithm, cluster_epochs,
-                                       local_epochs, merge_arrivals_only,
-                                       packed_async_row, staleness_merge,
-                                       tree_copy)
+                                       fold_losses, local_epochs,
+                                       merge_arrivals_only, packed_async_row,
+                                       staleness_merge, tree_copy)
 from repro.fed.driver import AsyncUpdate
 from repro.fed.client import evaluate, make_steps
 from repro.models.cnn import make_model
@@ -86,24 +86,6 @@ def cluster_by_stats(shards, cfg) -> np.ndarray:
         k = cfg.num_clusters
     res = kmeans.kmeans(key, feats, k)
     return np.asarray(res.assignments)
-
-
-def _fold_losses(per_wave):
-    """Combine per-wave ``(t_loss, t_cnt, s_loss, s_cnt)`` active-slot
-    means into cohort means, weighted by each wave's active-slot counts.
-    A single contributing wave passes its loss through UNTOUCHED — the
-    single-wave path must stay bit-identical to the monolithic round."""
-    def one(vals):
-        vals = [(lo, int(c)) for lo, c in vals if c > 0]
-        if not vals:
-            return 0.0
-        if len(vals) == 1:
-            return vals[0][0]
-        tot = float(sum(c for _, c in vals))
-        return float(sum(lo * c for lo, c in vals) / tot)
-
-    return (one([(tl, tc) for tl, tc, _, _ in per_wave]),
-            one([(sl, sc) for _, _, sl, sc in per_wave]))
 
 
 class _ClusteredKDBase(Algorithm):
@@ -786,12 +768,8 @@ class ShardedClusteredKD(_ClusteredKDBase):
                     # double-buffer: wave w+1's host gather + device_put
                     # run behind wave w's (async-dispatched) compute
                     self.stager.prefetch(plan.wave(w + 1))
-                # the two loss reads block until the wave's program ends:
-                # wave w+1 is not dispatched before then
-                with perf.span("sync"):
-                    perf.count("host_syncs", 2)
-                    losses.append((float(t_loss), t_lanes.sum(),
-                                   float(s_loss), (s_n > 0).sum()))
+                losses.append((t_loss, t_lanes.sum(), s_loss,
+                               (s_n > 0).sum()))
             with perf.span("aggregate"):
                 # leader mode merges the rows whose budget was non-zero;
                 # cluster mode scatters from each cluster's first slot
@@ -811,7 +789,9 @@ class ShardedClusteredKD(_ClusteredKDBase):
                         params=sh.take_rows(sp_local,
                                             jax.device_put(int(t)))))
         self.tp_k, self.ts_k = tp_acc, ts_acc
-        t_loss, s_loss = _fold_losses(losses)
+        # device values: the driver reads them a round later
+        t_loss = fold_losses([(tl, tc) for tl, tc, _, _ in losses])
+        s_loss = fold_losses([(sl, sc) for _, _, sl, sc in losses])
         # one wave: its aggregate IS the cohort mean, untouched (bit-
         # identical to the monolithic path); else fold the partial sums
         sp0 = partials[0] if len(partials) == 1 else agg.fold_partials(

@@ -27,6 +27,7 @@ from __future__ import annotations
 import jax
 import numpy as np
 
+from repro import perf
 from repro.core import aggregation as agg
 from repro.core import hierarchical
 from repro.fed import schedule
@@ -93,13 +94,19 @@ class FLHC(Algorithm):
 
     def eval(self):
         # client-weighted mean over cluster models on the global test set
-        # (full-population cluster sizes, independent of this round's sample)
+        # (full-population cluster sizes, independent of this round's
+        # sample); the Python arithmetic needs host numbers, so the
+        # clusters' evals are dispatched together and read at once
+        outs = [evaluate(self.steps["eval"], cm, self.test_set)
+                for cm in self.cluster_models]
+        with perf.span("sync"):
+            perf.count("host_syncs")
+            outs = jax.device_get(outs)
         accs, losses, ws = [], [], []
-        for cm, c in zip(self.cluster_models, self.clusters):
-            a, l = evaluate(self.steps["eval"], cm, self.test_set)
+        for (a, l), c in zip(outs, self.clusters):
             w = sum(self.shards[i].num_examples for i in c)
-            accs.append(a * w)
-            losses.append(l * w)
+            accs.append(float(a) * w)
+            losses.append(float(l) * w)
             ws.append(w)
         return sum(accs) / sum(ws), sum(losses) / sum(ws)
 

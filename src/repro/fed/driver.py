@@ -13,6 +13,12 @@ sharded paths of the old ``rounds.py`` monolith:
   ``labels_history`` (one ``[round, labels]`` entry per re-clustering);
 - eval/record: after every round, acc AND loss on the test set, printed
   identically for every algorithm under ``progress=True``;
+- the one-round lead (DESIGN.md §13): a round's metrics, acc and loss may
+  come back as device values; the driver reads them (``_settle``: one
+  ``jax.device_get``) only after it has dispatched the NEXT round, so the
+  host plans and stages round r+1 while the device runs round r.  At most
+  one round is ever unread when ``run_round`` is entered; a round that
+  saves a checkpoint, and the last round, are read at once;
 - the running history (one schema for all algorithms/engines, plus the
   strategy's ``history_extras`` and per-round ``run_round`` metrics).
   Per-round metric lists stay ROUND-ALIGNED even when a strategy emits a
@@ -46,9 +52,10 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
+import numpy as np
 
 from repro import guards, perf
 from repro.data.pipeline import ClientStore, make_client_shards
@@ -83,6 +90,20 @@ EXECUTION_ONLY = frozenset({
     "rounds", "ckpt_dir", "ckpt_every", "ckpt_keep", "resume",
     "donate", "prefetch", "async_ckpt", "guards",
 })
+
+
+class _Unread(NamedTuple):
+    """One dispatched round whose values the driver has not read yet:
+    ``token`` is its ``perf.round_token()`` (the read counts in its own
+    round's bucket), ``metrics`` None for a setup round (no
+    ``run_round``)."""
+
+    rnd: int
+    token: int
+    metrics: dict | None
+    participants: int
+    acc: Any
+    loss: Any
 
 
 @dataclasses.dataclass
@@ -278,8 +299,9 @@ class RoundDriver:
             # rounds consumed by setup itself (FL+HC's clustering pre-round
             # trains every client and IS the run's round 1)
             for rnd in range(1, min(alg.setup_rounds, cfg.rounds) + 1):
-                history["participants"].append(cfg.num_clients)
-                self._record(history, rnd)
+                self._settle(history, _Unread(
+                    rnd, perf.round_token(), None, cfg.num_clients,
+                    *alg.eval()), lag=0)
                 self._save(history, fp, rnd)
             start_round = min(alg.setup_rounds, cfg.rounds)
 
@@ -303,6 +325,7 @@ class RoundDriver:
                 # LRU eviction, async checkpoint submit/drain — stretch
                 # the interleavings; the history must not change by a bit
                 guards.enable_jitter(cfg.seed)
+        unread = None                   # the round dispatched last
         try:
             for rnd in range(start_round + 1, cfg.rounds + 1):
                 guarded = guard_from is not None and rnd >= guard_from
@@ -347,10 +370,19 @@ class RoundDriver:
                             metrics["buffered"] = len(self.buffer)
                         else:
                             metrics.update(alg.run_round(plan, rnd))
-                    self._append_metrics(history, metrics)
-                    history["participants"].append(int(plan.active.sum()))
                 with perf.span("eval"):
-                    self._record(history, rnd)
+                    acc, loss = alg.eval()
+                # round rnd is dispatched: now read round rnd-1
+                done, unread = unread, _Unread(
+                    rnd, perf.round_token(), metrics, int(plan.active.sum()),
+                    acc, loss)
+                if done is not None:
+                    self._settle(history, done, lag=1)
+                if rnd == cfg.rounds or self._saves(rnd):
+                    # a checkpoint holds its own round's history, and the
+                    # run returns with every round read
+                    self._settle(history, unread, lag=0)
+                    unread = None
                 with perf.span("checkpoint"):
                     self._save(history, fp, rnd)
                 perf.end_round()
@@ -394,18 +426,43 @@ class RoundDriver:
                 lst.extend([None] * (n_prev - len(lst)))
             lst.append(metrics.get(k))
 
-    def _record(self, history, rnd):
-        acc, loss = self.alg.eval()
+    def _settle(self, history, unread, lag):
+        """Read one round's values and append them to ``history`` in round
+        order.  Device values come back in one ``jax.device_get``
+        (``perf``: ``settle`` > ``sync``, one ``host_syncs``, and
+        ``read_lag``, the rounds dispatched after this one, all in that
+        round's own bucket); host floats pass through unread."""
+        values = (unread.metrics, unread.acc, unread.loss)
+        if any(isinstance(v, jax.Array)
+               for v in jax.tree_util.tree_leaves(values)):
+            tok = unread.token
+            with perf.span("settle", round_id=tok), \
+                    perf.span("sync", round_id=tok):
+                perf.count("host_syncs", round_id=tok)
+                perf.count("read_lag", lag, round_id=tok)
+                values = jax.tree_util.tree_map(
+                    lambda v: v.item() if isinstance(v, np.ndarray)
+                    and not v.ndim else v,
+                    jax.device_get(values))
+        metrics, acc, loss = values
+        if metrics is not None:
+            self._append_metrics(history, metrics)
+        history["participants"].append(unread.participants)
         history["acc"].append(acc)
         history["loss"].append(loss)
-        history["round"].append(rnd)
+        history["round"].append(unread.rnd)
         if self.progress:
-            print(f"  round {rnd:3d}  acc={acc:.4f}  loss={loss:.4f}  "
-                  f"clients={history['participants'][-1]}")
+            print(f"  round {unread.rnd:3d}  acc={acc:.4f}  loss={loss:.4f}  "
+                  f"clients={unread.participants}")
+
+    def _saves(self, rnd):
+        cfg = self.cfg
+        return bool(cfg.ckpt_dir) and (rnd % cfg.ckpt_every == 0
+                                       or rnd == cfg.rounds)
 
     def _save(self, history, fp, rnd):
         cfg = self.cfg
-        if cfg.ckpt_dir and (rnd % cfg.ckpt_every == 0 or rnd == cfg.rounds):
+        if self._saves(rnd):
             arrays = self.alg.checkpoint_arrays()
             buffer_meta = []
             if self.buffer is not None:

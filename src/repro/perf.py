@@ -34,15 +34,18 @@ Contract:
 - ``count(name, n)`` adds ``n`` to a per-round counter, kept apart from
   the seconds buckets (``export()["counts"]``), so a reader that sums a
   bucket never meets a count.  The round path counts ``host_syncs``
-  (blocking device-to-host reads) and ``h2d_bytes`` (host arrays handed
-  to the device: stager gathers, per-wave operands, eval batches).
+  (blocking device-to-host reads), ``read_lag`` (rounds the driver had
+  dispatched after a round when it read it) and ``h2d_bytes`` (host
+  arrays handed to the device: stager gathers, per-wave operands, the
+  test set).
 - ``end_round()`` closes the current round — the driver calls it once per
   completed round (warm-up/setup time lands in the round that follows it,
   i.e. the first bucket; steady-state consumers should skip bucket 0,
   which also carries jit compilation).
-- Thread attribution: work that RUNS on a background thread but BELONGS to
-  a specific round — a stager's prefetch gather, the async checkpoint
-  writer's device-to-host copy and npz write — is recorded with
+- Thread attribution: work that RUNS on a background thread or in a later
+  round but BELONGS to a specific round — a stager's prefetch gather, the
+  async checkpoint writer's device-to-host copy and npz write, the
+  driver's read of a round's values one round late — is recorded with
   ``span(name, round_id=token)`` (and ``count(..., round_id=token)``)
   where the token was captured on the submitting thread via
   ``round_token()``.  Such a span lands in its submission round even when
@@ -55,7 +58,8 @@ Contract:
 
 Spans measure dispatch-side wall-clock: jax dispatch is asynchronous, so a
 phase that merely enqueues device work attributes the wait to whichever
-later span blocks — the ``sync`` spans around the loss and eval reads.
+later span blocks — the ``sync`` span of the driver's read of a round's
+losses and eval.
 """
 from __future__ import annotations
 

@@ -27,7 +27,6 @@ _SCRIPT = textwrap.dedent("""
     from repro.data.pipeline import ClientStore, make_client_shards
     from repro.data.synthetic import load_dataset
     from repro.fed.algorithms import make_algorithm
-    from repro.fed.algorithms.clustered_kd import _fold_losses
     from repro.fed.lifecycle import ClientLifecycle
     from repro.fed.rounds import FedConfig
 
@@ -138,7 +137,16 @@ _SCRIPT = textwrap.dedent("""
                            (s_n > 0).sum()))
         sp_new = (partials[0] if len(partials) == 1
                   else agg.fold_partials(partials))
-        return acc + (sp_new,) + _fold_losses(losses)
+        return acc + (sp_new,) + fold(losses)
+
+    def fold(per_wave):
+        # cohort means of per-wave active-slot means, weighted by counts
+        def one(vals):
+            vals = [(lo, c) for lo, c in vals if c > 0]
+            tot = sum(c for _, c in vals)
+            return sum(lo * c for lo, c in vals) / tot if tot else 0.0
+        return (one([(tl, tc) for tl, tc, _, _ in per_wave]),
+                one([(sl, sc) for _, _, sl, sc in per_wave]))
 
     def close(a, b, what):
         for x, y in zip(jax.tree_util.tree_leaves(a),
